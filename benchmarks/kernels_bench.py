@@ -31,8 +31,8 @@ def run():
     # gqa decode
     B, Hq, Hkv, D, S = 4, 16, 4, 128, 8192
     q = jnp.asarray(rng.normal(size=(B, Hq, D)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(B, Hkv, S, D)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(B, Hkv, S, D)), jnp.float32)
     lens = jnp.full((B,), S, jnp.int32)
     f = jax.jit(ref.gqa_decode)
     us = timeit(lambda: f(q, k, v, lens))

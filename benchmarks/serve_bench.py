@@ -125,6 +125,8 @@ def main(argv=None) -> dict:
     ap.add_argument("--max-batch", type=int, default=4)
     args = ap.parse_args(argv)
 
+    from repro.api import enable_compile_cache
+    enable_compile_cache()
     t0 = time.perf_counter()
     entry = run(args.graph, args.requests, args.max_batch)
     total = time.perf_counter() - t0

@@ -29,6 +29,7 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import ARCH_IDS, get_config
 from repro.core.layout import Layout
 from repro.core.layoutloop import EvalConfig
+from repro.core.workloads import init_graph_weights
 from repro.data import DataConfig, SyntheticLMStream, make_stream
 from repro.distributed.stepfn import make_train_step
 from repro.launch.mesh import make_local_mesh
@@ -44,6 +45,7 @@ from repro.plan import upgrade_plan as _upgrade_plan
 from repro.plan import plan_network as _plan_network
 from repro.plan import execute_network as _execute_network
 from repro.runtime import TrainSupervisor
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serve import QueueFullError, ServeConfig, ServeEngine, ServeTicket
 
 from repro import obs as _obs
@@ -102,6 +104,7 @@ __all__ = [
     "EvalConfig", "Layout", "LayerGraph", "PlannerOptions", "ExecutionPlan",
     "PlanCache", "ResolvedPlan",
     "from_layers", "resnet50_graph", "mobilenet_v3_graph", "from_arch_config",
+    "init_graph_weights",
     "plan_network", "resolve_plan", "upgrade_plan",
     # execution
     "PreparedNetwork", "prepare_network", "execute_network",
@@ -115,6 +118,8 @@ __all__ = [
     "DataConfig", "SyntheticLMStream", "make_stream", "make_train_step",
     "make_local_mesh", "adamw_init", "adamw_update", "wsd_schedule",
     "CheckpointManager", "TrainSupervisor",
+    # entry-point set-up: the persistent compilation cache's directory
+    "enable_compile_cache",
     # deprecation helper (for legacy shims, not applications)
     "warn_deprecated",
 ]

@@ -21,7 +21,6 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import ArchConfig
 from repro.models.common import activation, apply_norm, dense
@@ -127,8 +126,8 @@ def moe_apply_ep(cfg: ArchConfig, p: Dict, x: jax.Array,
             combined = combined + dense(act_s, wd_s)
         return combined.reshape(B_loc, T_loc, D)
 
-    fn = shard_map(local, mesh=mesh, in_specs=(in_specs[0], x_spec),
-                   out_specs=x_spec, check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(in_specs[0], x_spec),
+                       out_specs=x_spec, check_vma=False)
     return fn(p_in, x)
 
 

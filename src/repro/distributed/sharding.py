@@ -241,11 +241,11 @@ def cache_shardings(mesh: Mesh, cache_specs: Pytree) -> Pytree:
         core = shape[1:] if stacked else shape
         if len(core) == 4 and ("k" in p.split("/")[-1] or
                                "v" in p.split("/")[-1]) and "conv" not in p:
-            # attn kv (B, S, Hkv, dh)
-            if core[2] % msize == 0:
-                spec = P(data, None, "model", None)
-            else:
+            # attn kv, head-major (B, Hkv, S, dh)
+            if core[1] % msize == 0:
                 spec = P(data, "model", None, None)
+            else:
+                spec = P(data, None, "model", None)
         elif len(core) == 4:    # ssm (B, H, state, hd) / rwkv (B, H, dk, dv)
             spec = P(data, "model", None, None)
         elif len(core) == 3:    # conv cache (B, W-1, C)

@@ -23,8 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-from ._compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.birrd import ADD_LEFT, ADD_RIGHT, PASS, SWAP, Birrd
 
@@ -88,7 +87,7 @@ def _kernel(m_ref, x_ref, o_ref, *, num_stages: int):
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def birrd_apply_p(x: jax.Array, stage_mats: jax.Array, *, block_d: int = 128,
-                  interpret: bool = True) -> jax.Array:
+                  interpret: bool) -> jax.Array:
     """Push ``x`` (aw, d) through a compiled BIRRD switch program."""
     aw, d = x.shape
     S = stage_mats.shape[0]
@@ -103,14 +102,14 @@ def birrd_apply_p(x: jax.Array, stage_mats: jax.Array, *, block_d: int = 128,
         ],
         out_specs=pl.BlockSpec((aw, block_d), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((aw, d), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(stage_mats, x)
 
 
 def birrd_apply(x: jax.Array, configs, *, block_d: int = 128,
-                interpret: bool = True) -> jax.Array:
+                interpret: bool) -> jax.Array:
     """Route ``x`` (aw, d) through BIRRD configured by ``configs``."""
     mats = jnp.asarray(compile_switch_program(x.shape[0], configs))
     return birrd_apply_p(x, mats, block_d=block_d, interpret=interpret)
@@ -139,7 +138,7 @@ def _out_port_mask(aw: int, out_ports: Tuple[int, ...]) -> np.ndarray:
 
 def birrd_reduce(x: jax.Array, group_ids: Sequence[int],
                  out_ports: Sequence[int], *, block_d: int = 128,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool) -> jax.Array:
     """Route + execute: grouped reduction with arbitrary output reorder.
 
     x: (aw, d).  Returns (aw, d) with group sums at their target ports and

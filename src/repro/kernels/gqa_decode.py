@@ -6,6 +6,11 @@ online-softmax running (max, sum, acc) state in VMEM scratch.  The grouped
 queries of one KV head (G = Hq/Hkv rows) ride the sublane axis — the same
 grouped-reduction structure BIRRD exploits (a G:1 reduction group per KV
 head), with the MXU doing the (G, D) x (D, bs) score tile.
+
+The cache is head-major, (B, Hkv, S, D): a KV block is then a (block_s, D)
+tile in the last two dims, which Mosaic requires to be (8, 128)-divisible
+or full — a head-minor (B, S, Hkv, D) cache would put a 1-wide head block
+in the second-minor dim.
 """
 from __future__ import annotations
 
@@ -15,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from ._compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -33,9 +36,11 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)                 # (G, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)           # (bs, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)           # (bs, Dv)
-    scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+    k = k_ref[0, 0].astype(jnp.float32)                 # (bs, D)
+    v = v_ref[0, 0].astype(jnp.float32)                 # (bs, Dv)
+    scores = jax.lax.dot_general(                       # q @ k.T
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
 
     length = len_ref[pl.program_id(0)]
     pos = sb * block_s + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
@@ -60,13 +65,15 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def gqa_decode(q: jax.Array, k: jax.Array, v: jax.Array,
                lengths: jax.Array, *, block_s: int = 512,
-               interpret: bool = True) -> jax.Array:
-    """q: (B, Hq, D); k/v: (B, S, Hkv, D); lengths: (B,) int32 -> (B, Hq, D)."""
+               interpret: bool) -> jax.Array:
+    """q: (B, Hq, D); k/v: (B, Hkv, S, D); lengths: (B,) int32 -> (B, Hq, D).
+
+    ``block_s`` must divide S (``ops.gqa_decode`` picks or pads for it).
+    """
     B, Hq, D = q.shape
-    _, S, Hkv, Dv = v.shape
+    _, Hkv, S, Dv = v.shape
     G = Hq // Hkv
-    assert Hq == G * Hkv and k.shape == (B, S, Hkv, D)
-    block_s = min(block_s, S)
+    assert Hq == G * Hkv and k.shape == (B, Hkv, S, D)
     assert S % block_s == 0, (S, block_s)
     s_steps = S // block_s
     scale = 1.0 / (D ** 0.5)
@@ -81,10 +88,10 @@ def gqa_decode(q: jax.Array, k: jax.Array, v: jax.Array,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, 1, G, D), lambda b, h, s, lens: (b, h, 0, 0)),
-                pl.BlockSpec((1, block_s, 1, D),
-                             lambda b, h, s, lens: (b, s, h, 0)),
-                pl.BlockSpec((1, block_s, 1, Dv),
-                             lambda b, h, s, lens: (b, s, h, 0)),
+                pl.BlockSpec((1, 1, block_s, D),
+                             lambda b, h, s, lens: (b, h, s, 0)),
+                pl.BlockSpec((1, 1, block_s, Dv),
+                             lambda b, h, s, lens: (b, h, s, 0)),
             ],
             out_specs=pl.BlockSpec((1, 1, G, Dv),
                                    lambda b, h, s, lens: (b, h, 0, 0)),
@@ -95,7 +102,7 @@ def gqa_decode(q: jax.Array, k: jax.Array, v: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dv), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths.astype(jnp.int32), qg, k, v)

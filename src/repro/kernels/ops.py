@@ -1,18 +1,21 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On non-TPU backends (this container is CPU-only) the kernels run in
-``interpret=True`` mode, which executes the kernel bodies for correctness;
-on TPU the same BlockSpecs compile to Mosaic.  ``use_kernels(False)`` swaps
-in the pure-jnp references (used by the dry-run so lowering stays pure XLA).
+The platform picks the mode: on a TPU the kernels compile to Mosaic; on the
+CPU (the test suite) they run in ``interpret=True`` mode, which executes the
+kernel bodies for correctness.  Any other backend raises — a TPU that failed
+to initialize must not quietly run every kernel interpreted.
+``use_kernels(False)`` swaps in the pure-jnp references (used by the dry-run
+so lowering stays pure XLA).
 """
 from __future__ import annotations
 
 from typing import Optional, Sequence
 
 import jax
+import jax.numpy as jnp
 
 from . import ref
-from .birrd_reduce import birrd_apply, birrd_reduce as _birrd_reduce
+from .birrd_reduce import birrd_reduce as _birrd_reduce
 from .gqa_decode import gqa_decode as _gqa_decode
 from .linear_scan import linear_scan as _linear_scan
 from .rir_matmul import rir_matmul as _rir_matmul
@@ -29,8 +32,15 @@ def kernels_enabled() -> bool:
     return _KERNELS_ENABLED
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _interpret(backend: Optional[str] = None) -> bool:
+    """Interpret mode on the CPU, compiled kernels on a TPU; nothing else."""
+    backend = backend or jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas kernels run compiled on 'tpu' or interpreted "
+                       f"on 'cpu'; the {backend!r} backend has neither")
 
 
 def rir_matmul(a: jax.Array, b: jax.Array,
@@ -50,7 +60,6 @@ def rir_matmul(a: jax.Array, b: jax.Array,
 
 def birrd_reduce(x: jax.Array, group_ids: Sequence[int],
                  out_ports: Sequence[int], *, block_d: int = 128) -> jax.Array:
-    import jax.numpy as jnp
     if not _KERNELS_ENABLED:
         gi = jnp.asarray(list(group_ids), jnp.int32)
         op = jnp.asarray(list(out_ports), jnp.int32)
@@ -59,12 +68,31 @@ def birrd_reduce(x: jax.Array, group_ids: Sequence[int],
                          block_d=block_d, interpret=_interpret())
 
 
+def _decode_block(S: int, block_s: int) -> int:
+    """KV block for a length-S cache: the whole cache if it fits one block,
+    else the largest multiple of 8 in [block_s / 4, block_s] dividing S,
+    else ``block_s`` (the caller pads S up to it)."""
+    if S <= block_s:
+        return S
+    for bs in range(block_s - block_s % 8, max(8, block_s // 4) - 1, -8):
+        if S % bs == 0:
+            return bs
+    return block_s
+
+
 def gqa_decode(q: jax.Array, k: jax.Array, v: jax.Array,
                lengths: jax.Array, *, block_s: int = 512) -> jax.Array:
-    S = k.shape[1]
-    if not _KERNELS_ENABLED or S % min(block_s, S) != 0:
+    """q: (B, Hq, D); k/v: (B, Hkv, S, D) head-major cache; lengths: (B,)."""
+    if not _KERNELS_ENABLED:
         return ref.gqa_decode(q, k, v, lengths)
-    return _gqa_decode(q, k, v, lengths, block_s=block_s,
+    S = k.shape[2]
+    bs = _decode_block(S, block_s)
+    pad = -S % bs
+    if pad:
+        # positions past S are masked by ``lengths`` like any unfilled slot
+        widths = ((0, 0), (0, 0), (0, pad), (0, 0))
+        k, v = jnp.pad(k, widths), jnp.pad(v, widths)
+    return _gqa_decode(q, k, v, lengths, block_s=bs,
                        interpret=_interpret())
 
 
@@ -98,5 +126,5 @@ def linear_scan(q: jax.Array, k: jax.Array, v: jax.Array,
     return _linear_scan_ad(q, k, v, log_decay)
 
 
-__all__ = ["rir_matmul", "birrd_reduce", "birrd_apply", "gqa_decode",
+__all__ = ["rir_matmul", "birrd_reduce", "gqa_decode",
            "linear_scan", "use_kernels", "kernels_enabled"]

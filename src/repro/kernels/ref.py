@@ -89,21 +89,21 @@ def gqa_decode(q: jax.Array, k: jax.Array, v: jax.Array,
                scale: Optional[float] = None) -> jax.Array:
     """Single-token GQA decode attention.
 
-    q: (B, Hq, D); k/v: (B, S, Hkv, D); lengths: (B,) valid KV length.
-    Hq = G * Hkv.  Returns (B, Hq, D).
+    q: (B, Hq, D); k/v: (B, Hkv, S, D) head-major cache; lengths: (B,)
+    valid KV length.  Hq = G * Hkv.  Returns (B, Hq, D).
     """
     B, Hq, D = q.shape
-    _, S, Hkv, _ = k.shape
+    _, Hkv, S, _ = k.shape
     G = Hq // Hkv
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
     qg = q.reshape(B, Hkv, G, D)
-    scores = jnp.einsum("bhgd,bshd->bhgs", qg.astype(jnp.float32),
+    scores = jnp.einsum("bhgd,bhsd->bhgs", qg.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
     if lengths is not None:
         mask = jnp.arange(S)[None, None, None, :] < lengths[:, None, None, None]
         scores = jnp.where(mask, scores, -jnp.inf)
     w = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhgs,bshd->bhgd", w, v.astype(jnp.float32))
+    out = jnp.einsum("bhgs,bhsd->bhgd", w, v.astype(jnp.float32))
     return out.reshape(B, Hq, D).astype(q.dtype)
 
 

@@ -22,7 +22,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
+
+def _dot(a, b):
+    # float32 operands get a float32-exact MXU contraction, pinned rather
+    # than left to the compiler's default precision
+    prec = jax.lax.Precision.HIGHEST if a.dtype == jnp.float32 else None
+    return jnp.dot(a, b, precision=prec, preferred_element_type=jnp.float32)
 
 
 def _kernel(perm_ref, a_ref, b_ref, o_ref, acc_ref, *, k_steps: int):
@@ -31,8 +36,7 @@ def _kernel(perm_ref, a_ref, b_ref, o_ref, acc_ref, *, k_steps: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(a_ref[...], b_ref[...],
-                            preferred_element_type=jnp.float32)
+    acc_ref[...] += _dot(a_ref[...], b_ref[...])
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _emit():
@@ -52,8 +56,7 @@ def _kernel_res(perm_ref, a_ref, b_ref, r_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(a_ref[...], b_ref[...],
-                            preferred_element_type=jnp.float32)
+    acc_ref[...] += _dot(a_ref[...], b_ref[...])
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _emit():
@@ -66,7 +69,7 @@ def _kernel_res(perm_ref, a_ref, b_ref, r_ref, o_ref, acc_ref, *,
 def rir_matmul_p(a: jax.Array, b: jax.Array, out_block_perm: jax.Array, *,
                  residual: jax.Array | None = None,
                  block_m: int = 128, block_n: int = 128, block_k: int = 128,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool) -> jax.Array:
     """``(a @ b)`` with output N-blocks scattered per ``out_block_perm``.
 
     a: (M, K), b: (K, N); out_block_perm: int32[(N//block_n,)] permutation
@@ -111,7 +114,7 @@ def rir_matmul_p(a: jax.Array, b: jax.Array, out_block_perm: jax.Array, *,
             scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((M, N), a.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(out_block_perm.astype(jnp.int32), *operands)
@@ -121,7 +124,7 @@ def rir_matmul(a: jax.Array, b: jax.Array,
                out_block_perm: Sequence[int] | None = None, *,
                residual: jax.Array | None = None,
                block_m: int = 128, block_n: int = 128, block_k: int = 128,
-               interpret: bool = True) -> jax.Array:
+               interpret: bool) -> jax.Array:
     n_blocks = b.shape[1] // block_n
     if out_block_perm is None:
         out_block_perm = tuple(range(n_blocks))

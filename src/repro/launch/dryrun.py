@@ -1,5 +1,8 @@
 # check: ignore-file[api-boundary]  (operator dev tool: inspects internals by design)
 import os
+# compiles on placeholder host devices: never take an accelerator, here or
+# in the children ``--all`` spawns (they inherit this environment)
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", ""))

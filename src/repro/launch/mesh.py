@@ -5,17 +5,15 @@ lazily by functions, and the dry-run sets XLA_FLAGS before any jax import.
 """
 from __future__ import annotations
 
+from typing import Optional, Sequence
+
 import jax
 
 
-def _mesh(shape, axes):
-    # jax < 0.5 has no sharding.AxisType / axis_types kwarg (everything is
-    # implicitly Auto there); newer jax wants it spelled out.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+def _mesh(shape, axes, devices=None):
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -25,8 +23,16 @@ def make_production_mesh(*, multi_pod: bool = False):
     return _mesh(shape, axes)
 
 
-def make_local_mesh(model_axis: int = 1):
-    """Degenerate mesh over the locally visible devices (tests / smoke)."""
-    n = len(jax.devices())
-    data = n // model_axis
-    return _mesh((data, model_axis), ("data", "model"))
+def make_local_mesh(model_axis: int = 1,
+                    devices: Optional[Sequence] = None):
+    """(data, model) mesh over ``devices`` (default: every visible device).
+
+    A caller that means to use some of the host's chips passes them: a
+    one-chip phase passes ``jax.devices()[:1]``.
+    """
+    devices = list(jax.devices() if devices is None else devices)
+    if len(devices) % model_axis:
+        raise ValueError(f"model_axis={model_axis} does not divide "
+                         f"{len(devices)} devices")
+    return _mesh((len(devices) // model_axis, model_axis),
+                 ("data", "model"), devices)

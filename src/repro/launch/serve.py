@@ -54,8 +54,9 @@ def _decode_block_hints(plan):
 
 
 def main() -> None:
-    from repro.api import ServeConfig, ServeEngine
+    from repro.api import ServeConfig, ServeEngine, enable_compile_cache
 
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ServeConfig.add_args(ap)
     args = ap.parse_args()

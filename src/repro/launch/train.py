@@ -44,13 +44,15 @@ def main() -> None:
                     help="console log threshold (default: REPRO_LOG or info)")
     args = ap.parse_args()
 
+    from repro.api import (CheckpointManager, DataConfig, SyntheticLMStream,
+                           adamw_init, build_model, enable_compile_cache,
+                           get_config, make_local_mesh, make_train_step,
+                           wsd_schedule)
+
+    enable_compile_cache()
     obs.configure_from_env()          # REPRO_TRACE=path enables tracing
     if args.log_level:
         obs.set_level(args.log_level)
-
-    from repro.api import (CheckpointManager, DataConfig, SyntheticLMStream,
-                           adamw_init, build_model, get_config,
-                           make_local_mesh, make_train_step, wsd_schedule)
 
     cfg = get_config(args.arch, smoke=args.smoke)
     model = build_model(cfg)

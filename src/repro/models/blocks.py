@@ -93,7 +93,8 @@ def cross_attn_train(cfg: ArchConfig, p: Dict, x: jax.Array,
 
 def attn_prefill(cfg: ArchConfig, p: Dict, x: jax.Array, use_rope: bool = True
                  ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
-    """Returns (residual delta, (k_cache, v_cache)) for the prompt."""
+    """Returns (residual delta, (k_cache, v_cache)) for the prompt, the
+    caches head-major (B, Hkv, T, dh) as ``gqa_decode`` reads them."""
     B, T, D = x.shape
     q, k, v = _qkv(cfg, p, x)
     if use_rope:
@@ -101,17 +102,39 @@ def attn_prefill(cfg: ArchConfig, p: Dict, x: jax.Array, use_rope: bool = True
         q = apply_rope(q, pos, cfg.rope_theta)
         k = apply_rope(k, pos, cfg.rope_theta)
     o = chunked_attention(q, k, v, causal=True)
-    return dense(o.reshape(B, T, -1), p["wo"]), (k, v)
+    return dense(o.reshape(B, T, -1), p["wo"]), (k.transpose(0, 2, 1, 3),
+                                                  v.transpose(0, 2, 1, 3))
+
+
+def _gqa_decode(q: jax.Array, k: jax.Array, v: jax.Array,
+                lengths: jax.Array, mesh) -> jax.Array:
+    """``ops.gqa_decode``, run per (batch, head) shard on a multi-device
+    mesh: XLA does not partition a Mosaic kernel by itself.  KV heads
+    split over the model axis where they divide it; each shard keeps its
+    queries' whole GQA group, since Hq is grouped KV-head-major."""
+    if mesh is None or mesh.size == 1:
+        return ops.gqa_decode(q, k, v, lengths)
+    from jax.sharding import PartitionSpec as P
+    data = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
+    dsize = math.prod(mesh.shape[a] for a in data)
+    batch = data if q.shape[0] % dsize == 0 else None
+    heads = "model" if k.shape[1] % mesh.shape["model"] == 0 else None
+    kv = P(batch, heads, None, None)
+    return jax.shard_map(
+        ops.gqa_decode, mesh=mesh,
+        in_specs=(P(batch, heads, None), kv, kv, P(batch)),
+        out_specs=P(batch, heads, None), check_vma=False)(q, k, v, lengths)
 
 
 def attn_decode(cfg: ArchConfig, p: Dict, x: jax.Array,
                 k_cache: jax.Array, v_cache: jax.Array, length: jax.Array,
-                use_rope: bool = True
+                use_rope: bool = True, mesh=None
                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One token step.  x: (B, D); caches: (B, S, Hkv, dh); length: (B,).
+    """One token step.  x: (B, D); caches: (B, Hkv, S, dh); length: (B,).
 
     Returns (residual delta (B, D), new k_cache, new v_cache).
-    The new token attends over length+1 entries via the flash-decode kernel.
+    The new token attends over length+1 entries via the flash-decode kernel
+    (per shard of ``mesh``, when one is given).
     """
     B, D = x.shape
     Hkv, dh = cfg.n_kv_heads, cfg.head_dim
@@ -123,9 +146,9 @@ def attn_decode(cfg: ArchConfig, p: Dict, x: jax.Array,
     # aliases in place under donation (the one-hot/where alternative
     # materializes full-cache temporaries)
     rows = jnp.arange(k_cache.shape[0])
-    k_cache = k_cache.at[rows, length].set(k[:, 0])
-    v_cache = v_cache.at[rows, length].set(v[:, 0])
-    o = ops.gqa_decode(q[:, 0], k_cache, v_cache, length + 1)
+    k_cache = k_cache.at[rows, :, length].set(k[:, 0])
+    v_cache = v_cache.at[rows, :, length].set(v[:, 0])
+    o = _gqa_decode(q[:, 0], k_cache, v_cache, length + 1, mesh)
     return dense(o.reshape(B, -1), p["wo"]), k_cache, v_cache
 
 

@@ -84,7 +84,7 @@ class EncDecModel(LMModel):
     def cache_specs(self, batch: int, max_seq: int) -> Dict:
         cfg = self.cfg
         dh, dt = cfg.head_dim, jnp.dtype(cfg.dtype)
-        kv = lambda s: jax.ShapeDtypeStruct((batch, s, cfg.n_kv_heads, dh), dt)
+        kv = lambda s: jax.ShapeDtypeStruct((batch, cfg.n_kv_heads, s, dh), dt)
         return {
             "layers": _stack_specs({"k": kv(max_seq), "v": kv(max_seq),
                                     "ck": kv(cfg.enc_frames),
@@ -114,12 +114,12 @@ class EncDecModel(LMModel):
             h = apply_norm(cfg.norm, memory, layer["cross"]["norm"])
             ckv = dense(h, layer["cross"]["wkv"]).reshape(
                 B, -1, 2 * cfg.n_kv_heads, cfg.head_dim)
-            return x, (k, v, ckv[..., :cfg.n_kv_heads, :],
-                       ckv[..., cfg.n_kv_heads:, :])
+            ckv = ckv.transpose(0, 2, 1, 3)       # head-major cache
+            return x, (k, v, ckv[:, :cfg.n_kv_heads], ckv[:, cfg.n_kv_heads:])
 
         x, (ks, vs, cks, cvs) = jax.lax.scan(body, x, params["dec_layers"])
         S = max_seq
-        pad = ((0, 0), (0, 0), (0, S - T), (0, 0), (0, 0))
+        pad = ((0, 0), (0, 0), (0, 0), (0, S - T), (0, 0))
         cache["layers"]["k"] = jnp.pad(ks, pad)
         cache["layers"]["v"] = jnp.pad(vs, pad)
         cache["layers"]["ck"] = cks
@@ -147,7 +147,7 @@ class EncDecModel(LMModel):
             h = apply_norm(cfg.norm, x, layer["cross"]["norm"])
             q = dense(h, layer["cross"]["wq"]).reshape(
                 B, cfg.n_heads, cfg.head_dim)
-            enc_len = jnp.full((B,), lc["ck"].shape[1], jnp.int32)
+            enc_len = jnp.full((B,), lc["ck"].shape[2], jnp.int32)
             o = ops.gqa_decode(q, lc["ck"], lc["cv"], enc_len)
             x = x + dense(o.reshape(B, -1), layer["cross"]["wo"])
             x = x + mlp_apply(cfg, layer["ffn"], x[:, None])[:, 0]

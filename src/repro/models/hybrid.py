@@ -86,9 +86,9 @@ class HybridModel(LMModel):
             "layers": _stack_specs(mamba2_cache_specs(cfg, batch),
                                    cfg.n_layers),
             "attn_k": jax.ShapeDtypeStruct(
-                (self.n_invocations, batch, max_seq, cfg.n_kv_heads, dh), dt),
+                (self.n_invocations, batch, cfg.n_kv_heads, max_seq, dh), dt),
             "attn_v": jax.ShapeDtypeStruct(
-                (self.n_invocations, batch, max_seq, cfg.n_kv_heads, dh), dt),
+                (self.n_invocations, batch, cfg.n_kv_heads, max_seq, dh), dt),
             "length": jax.ShapeDtypeStruct((batch,), jnp.int32),
         }
 
@@ -115,7 +115,8 @@ class HybridModel(LMModel):
                           sp["concat_proj"])
                 kc = jax.lax.dynamic_index_in_dim(ak, inv, 0, keepdims=False)
                 vc = jax.lax.dynamic_index_in_dim(av, inv, 0, keepdims=False)
-                d, kc, vc = attn_decode(cfg, sp["attn"], h, kc, vc, length)
+                d, kc, vc = attn_decode(cfg, sp["attn"], h, kc, vc, length,
+                                        mesh=self.mesh)
                 h = h + d
                 h = h + mlp_apply(cfg, sp["ffn"], h[:, None])[:, 0]
                 ak = jax.lax.dynamic_update_index_in_dim(ak, kc, inv, 0)

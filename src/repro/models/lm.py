@@ -150,8 +150,9 @@ class LMModel:
             return mamba2_cache_specs(cfg, batch)
         dh = cfg.head_dim
         dt = _dt(cfg)
-        return {"k": jax.ShapeDtypeStruct((batch, max_seq, cfg.n_kv_heads, dh), dt),
-                "v": jax.ShapeDtypeStruct((batch, max_seq, cfg.n_kv_heads, dh), dt)}
+        # head-major, as gqa_decode blocks it: (B, Hkv, S, dh)
+        kv = jax.ShapeDtypeStruct((batch, cfg.n_kv_heads, max_seq, dh), dt)
+        return {"k": kv, "v": kv}
 
     def cache_specs(self, batch: int, max_seq: int) -> Dict:
         return {
@@ -172,7 +173,7 @@ class LMModel:
         if cfg.family == "ssm":
             return mamba2_decode(cfg, layer_p, x, cache)
         delta, k, v = attn_decode(cfg, layer_p, x, cache["k"], cache["v"],
-                                  length)
+                                  length, mesh=self.mesh)
         return delta, {"k": k, "v": v}
 
     def _ffn_decode(self, layer: Dict, x: jax.Array) -> jax.Array:
@@ -221,8 +222,8 @@ class LMModel:
                 return x, (k, v)
 
             x, (ks, vs) = jax.lax.scan(body, x, params["layers"])
-            S = cache["layers"]["k"].shape[2]
-            pad = ((0, 0), (0, 0), (0, S - T), (0, 0), (0, 0))
+            S = cache["layers"]["k"].shape[3]
+            pad = ((0, 0), (0, 0), (0, 0), (0, S - T), (0, 0))
             cache["layers"]["k"] = jnp.pad(ks, pad).astype(_dt(cfg))
             cache["layers"]["v"] = jnp.pad(vs, pad).astype(_dt(cfg))
         else:

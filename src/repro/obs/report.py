@@ -12,9 +12,9 @@ and rank the worst offenders, which is exactly where the cost model needs
 work (and exactly the labeled data a learned surrogate trains on).
 
 Gap ratios are *relative* honesty checks, not absolute ones: the executor
-runs on whatever backend JAX has (CPU interpret mode in CI), so the
-interesting signal is the per-step SPREAD of measured/modeled, not its
-absolute scale.  The report therefore also prints each step's gap
+runs on the TPU, or on the CPU with Pallas in interpret mode (the tests),
+so the interesting signal is the per-step SPREAD of measured/modeled, not
+its absolute scale.  The report therefore also prints each step's gap
 normalized by the run's median gap (``rel``), which cancels the unknown
 backend constant.
 

@@ -134,8 +134,22 @@ def _clamp_block(extent: int, block: int) -> int:
                    max(MIN_KERNEL_BLOCK, _pow2_floor(extent))))
 
 
-def step_kernel_blocks(step: PlanStep, block: int = RIR_BLOCK
-                       ) -> Tuple[int, int]:
+# Mosaic tiles a block's last (lane) dim in 128s: the A operand's K block
+# must be a multiple of this, or span the whole (padded) K
+_LANE = 128
+
+
+def _align_k_block(bk: int, k: int) -> int:
+    """Raise a tile-derived K block to what the TPU compiler accepts: keep
+    it when it already covers K (the executor pads K up to it) or is
+    lane-aligned, else round it up to the next multiple of ``_LANE``."""
+    if bk >= k or bk % _LANE == 0:
+        return bk
+    return _LANE * -(-bk // _LANE)
+
+
+def step_kernel_blocks(step: PlanStep, block: int = RIR_BLOCK, *,
+                       k: Optional[int] = None) -> Tuple[int, int]:
     """(block_m, block_k) the kernel grid should use for this step.
 
     The plan's on-chip tiling bounds how many GEMM rows (``N*P*Q`` tile) and
@@ -152,10 +166,17 @@ def step_kernel_blocks(step: PlanStep, block: int = RIR_BLOCK
     pre-tiling behaviour.  The output feature axis always stays at
     ``block``: epilogue permutations are defined over ``RIR_BLOCK``-wide
     boundary-layout blocks.
+
+    The K block is then aligned for the TPU (``_align_k_block``) against
+    ``k``, the GEMM's actual reduction length — the executor's im2col
+    width, which follows the producer's channels rather than the
+    workload's ``C`` where the boundary adapter pads or truncates.  It
+    defaults to the workload's ``C * R * S``.
     """
-    if not step.tiles and not step.double_buffer:
-        return block, block
     wl = step.workload
+    k = wl.C * wl.R * wl.S if k is None else k
+    if not step.tiles and not step.double_buffer:
+        return block, _align_k_block(block, k)
     t = dict(step.tiles)
 
     def ext(d: str, size: int) -> int:
@@ -167,7 +188,8 @@ def step_kernel_blocks(step: PlanStep, block: int = RIR_BLOCK
         else step.double_buffer
     if db_iact:
         rows = max(1, rows // 2)
-    return _clamp_block(rows, block), _clamp_block(kdim, block)
+    return (_clamp_block(rows, block),
+            _align_k_block(_clamp_block(kdim, block), k))
 
 
 def fold_batchnorm(w: jax.Array, gamma, beta, mean, var,
@@ -302,7 +324,8 @@ class PreparedPlan:
         self.weights = tuple(weights)
         self.perms = _boundary_perms(plan, x_dim, weights, block)
         # per-step kernel blocking, derived from the plan's tiling
-        self.blocks = [step_kernel_blocks(s, block) for s in plan.steps]
+        self.blocks = [step_kernel_blocks(s, block, k=w.shape[0])
+                       for s, w in zip(plan.steps, weights)]
         self.w_eff = [
             permute_weight_blocks(w, self.perms[i], block)
             if len(self.perms[i]) > 1 else w
@@ -628,7 +651,8 @@ class PreparedNetwork:
             row_map = None if passthrough else jnp.asarray(_patch_row_map(
                 wl.N, h_in, w_in, wl.H, wl.W, wl.P, wl.Q, wl.R, wl.S,
                 wl.stride))
-            bm, bk = step_kernel_blocks(step, block)
+            bm, bk = step_kernel_blocks(step, block,
+                                        k=wl.R * wl.S * in_width)
             w_eff = _effective_conv_weight(wl, w, in_width, self.perms[i],
                                            block)
             w_eff = _pad_axis(_pad_axis(w_eff, bk, 0), block, 1)

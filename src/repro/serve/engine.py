@@ -153,13 +153,20 @@ class _NetworkBackend:
 
 
 class _LMBackend:
-    """LM serving through the existing prefill/decode path."""
+    """LM serving through the existing prefill/decode path.
+
+    The model runs on the first ``model_axis`` local devices, one (1,
+    model_axis) mesh: a one-chip deployment holds one chip even on a host
+    with more.  Parameters are created in place, laid out by the
+    ``distributed.sharding`` rules over that mesh.
+    """
 
     def __init__(self, config: ServeConfig, cache,
                  sleep: Callable[[float], None]):
         import jax
 
         from repro.configs import get_config
+        from repro.distributed.sharding import param_shardings
         from repro.launch.mesh import make_local_mesh
         from repro.models import build_model
 
@@ -182,10 +189,15 @@ class _LMBackend:
                     artifact=config.plan, deadline_s=config.plan_deadline,
                     sleep=sleep)
         self.model = build_model(self.cfg)
-        self.mesh = make_local_mesh(config.model_axis)
+        self.mesh = make_local_mesh(
+            config.model_axis, jax.devices()[:config.model_axis])
+        self.model.mesh = self.mesh
         init_key, _ = jax.random.split(jax.random.PRNGKey(config.seed))
-        self.params = self.model.init(init_key)
+        shardings = param_shardings(self.mesh, self.model.param_specs())
+        self.params = jax.jit(self.model.init,
+                              out_shardings=shardings)(init_key)
         self.decode = jax.jit(self.model.decode_step)
+        self.prefill = jax.jit(self.model.prefill, static_argnums=2)
         self.max_seq = config.prompt_len + config.gen
 
     @property
@@ -219,8 +231,8 @@ class _LMBackend:
                     cache, logits = self.decode(self.params, cache,
                                                 prompts[:, t])
             else:
-                cache, logits = self.model.prefill(self.params, prompts,
-                                                   self.max_seq)
+                cache, logits = self.prefill(self.params, prompts,
+                                             self.max_seq)
             logits = jax.block_until_ready(logits)
             t_prefill = time.perf_counter() - t0
             obs.observe("serve.prefill_ms", t_prefill * 1e3)
@@ -360,6 +372,14 @@ class ServeEngine:
         """The currently-serving ``ResolvedPlan`` (upgrades swap it)."""
         with self._swap_lock:
             return self._resolved
+
+    @property
+    def lm(self):
+        """``(model, params, mesh)`` an LM engine serves with, for callers
+        that check its outputs against a reference; ``None`` for a network
+        engine."""
+        b = self._backend
+        return (b.model, b.params, b.mesh) if self.config.arch else None
 
     def queue_depth(self) -> int:
         return self._queue.qsize()

@@ -119,3 +119,29 @@ def test_serve_step_multidevice():
         print("SERVE_OK")
     """)
     assert "SERVE_OK" in out
+
+
+def test_lm_engine_model_axis_splits_params_and_matches_one_device():
+    """``model_axis=2`` serves on the first two devices with parameters
+    split by the sharding rules, and the decode kernel runs per head
+    shard; its greedy tokens match the same engine on one device."""
+    out = _run_subprocess("""
+        import numpy as np, jax
+        from repro.api import ServeConfig, ServeEngine
+        prompts = [np.arange(8, dtype=np.int32) + i for i in range(2)]
+        toks = {}
+        for axis in (2, 1):
+            cfg = ServeConfig(arch="llama3p2_3b", smoke=True, max_batch=2,
+                              prompt_len=8, gen=4, model_axis=axis)
+            with ServeEngine(cfg) as eng:
+                toks[axis] = np.stack(eng.serve(prompts))
+                _, params, mesh = eng.lm
+            assert mesh.devices.size == axis, mesh
+            wq = params["layers"]["mixer"]["wq"]
+            shards = {s.device.id: s.data.shape for s in wq.addressable_shards}
+            assert len(shards) == axis, shards
+            assert all(s[-1] == wq.shape[-1] // axis for s in shards.values())
+        assert np.array_equal(toks[2], toks[1]), toks
+        print("TP_OK", toks[1].tolist())
+    """)
+    assert "TP_OK" in out

@@ -77,6 +77,28 @@ def test_single_conv_matches_ref_oracle(M, C, R, S, stride, P, Q):
         np.asarray(direct), rtol=1e-5, atol=1e-5)
 
 
+def test_lane_aligned_k_blocks_match_ref_oracle():
+    """The K block the executor picks is TPU-legal — a multiple of 128
+    lanes or the whole padded K — even where the plan's tile asks for a
+    64-wide one.  Steps with K in {128, 256} then run one and two K steps
+    of 128 and still reproduce the oracle."""
+    graph = from_layers([
+        ConvWorkload(M=256, C=128, P=8, Q=8, R=1, S=1, name="k128"),
+        ConvWorkload(M=128, C=256, P=8, Q=8, R=1, S=1, name="k256"),
+    ], "kpair")
+    plan = make_plan(graph)
+    plan = dataclasses.replace(plan, steps=tuple(
+        dataclasses.replace(s, tiles=(("C", 64),), double_buffer=False,
+                            buffer_alloc=())
+        for s in plan.steps))
+    ws = init_graph_weights(list(graph.layers), seed=3)
+    prepared = prepare_network(plan, graph, ws)
+    assert [(st.k_width, st.block_k, st.w_eff.shape[0])
+            for st in prepared.steps] == [(128, 128, 128), (256, 128, 256)]
+    y, y_ref, _ = run_both(graph, plan=plan, seed=3)
+    np.testing.assert_allclose(y, y_ref, rtol=1e-4, atol=1e-3)
+
+
 def test_depthwise_conv_matches_ref_oracle():
     wl = ConvWorkload(M=72, C=1, P=14, Q=14, R=5, S=5, stride=2, name="dw")
     assert is_depthwise(wl) and input_channels(wl) == 72

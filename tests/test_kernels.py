@@ -94,8 +94,8 @@ def test_birrd_reduce_memoizes_routing_and_lowering():
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_gqa_decode_sweep(b, hq, hkv, d, s, dtype):
     q = _arr((b, hq, d), dtype)
-    k = _arr((b, s, hkv, d), dtype)
-    v = _arr((b, s, hkv, d), dtype)
+    k = _arr((b, hkv, s, d), dtype)
+    v = _arr((b, hkv, s, d), dtype)
     lens = jnp.asarray(RNG.integers(s // 2, s + 1, size=b), jnp.int32)
     y = ops.gqa_decode(q, k, v, lens)
     yr = ref.gqa_decode(q, k, v, lens)
@@ -108,14 +108,45 @@ def test_gqa_decode_respects_lengths():
     """KV beyond `length` must not affect the output."""
     b, hq, hkv, d, s = 1, 4, 2, 64, 512
     q = _arr((b, hq, d))
-    k = _arr((b, s, hkv, d))
-    v = _arr((b, s, hkv, d))
+    k = _arr((b, hkv, s, d))
+    v = _arr((b, hkv, s, d))
     lens = jnp.asarray([256], jnp.int32)
     y1 = ops.gqa_decode(q, k, v, lens)
-    k2 = k.at[:, 300:].set(99.0)
-    v2 = v.at[:, 300:].set(-99.0)
+    k2 = k.at[:, :, 300:].set(99.0)
+    v2 = v.at[:, :, 300:].set(-99.0)
     y2 = ops.gqa_decode(q, k2, v2, lens)
     assert_allclose(np.asarray(y1), np.asarray(y2), rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("s", [600, 1100])
+def test_gqa_decode_ragged_cache_runs_the_kernel(s, monkeypatch):
+    """S % block_s != 0 no longer falls back to the reference: 600 takes a
+    dividing 200-wide block, 1100 (no multiple of 8 divides it) is padded
+    and masked by ``lengths``.  Either way the Pallas kernel runs."""
+    b, hq, hkv, d = 2, 8, 2, 64
+    q = _arr((b, hq, d))
+    k, v = _arr((b, hkv, s, d)), _arr((b, hkv, s, d))
+    lens = jnp.asarray([s, s // 2 + 3], jnp.int32)
+    want = np.asarray(ref.gqa_decode(q, k, v, lens))
+
+    def no_fallback(*a, **kw):
+        raise AssertionError("ops.gqa_decode fell back to ref.gqa_decode")
+
+    monkeypatch.setattr(ref, "gqa_decode", no_fallback)
+    assert "pallas_call" in str(jax.make_jaxpr(ops.gqa_decode)(q, k, v, lens))
+    y = ops.gqa_decode(q, k, v, lens, block_s=512)
+    assert_allclose(np.asarray(y), want, rtol=5e-4, atol=5e-4)
+
+
+def test_interpret_mode_only_on_cpu():
+    """Interpret mode is for the CPU; a TPU compiles; any other backend (a
+    TPU that failed to come up and left JAX elsewhere) is an error, never a
+    quiet interpreted run."""
+    assert ops._interpret("cpu") is True
+    assert ops._interpret("tpu") is False
+    for backend in ("gpu", "cuda", "rocm"):
+        with pytest.raises(RuntimeError, match=backend):
+            ops._interpret(backend)
 
 
 # ----------------------------------------------------------------- linear_scan

@@ -55,6 +55,8 @@ def test_train_driver_checkpoint_resume(tmp_path):
     uninterrupted run — the data stream is step-addressed)."""
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    # the launcher's compile cache goes where the caller says
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "jax_cache")
     root = os.path.dirname(os.path.dirname(__file__))
     base = [sys.executable, "-m", "repro.launch.train", "--arch",
             "minicpm_2b", "--smoke", "--batch", "4", "--seq", "32",
@@ -81,9 +83,10 @@ def test_train_driver_checkpoint_resume(tmp_path):
                                                  rel=1e-4)
 
 
-def test_serve_driver_generates():
+def test_serve_driver_generates(tmp_path):
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "jax_cache")
     root = os.path.dirname(os.path.dirname(__file__))
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.serve", "--arch", "rwkv6_1p6b",

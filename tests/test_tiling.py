@@ -150,7 +150,8 @@ def test_step_kernel_blocks_follow_the_tile():
     step = plan.steps[0]
     bm, bk = step_kernel_blocks(step)
     assert 8 <= bm <= RIR_BLOCK       # 8 = Pallas f32 sublane floor
-    assert 8 <= bk <= RIR_BLOCK
+    # K = 256*3*3 spans many blocks, so the K block is lane-aligned
+    assert bk == RIR_BLOCK
     # tile-less single-buffered steps keep the full hardcoded block (v1)
     untiled = dataclasses.replace(step, tiles=(), double_buffer=False,
                                   buffer_alloc=())
@@ -179,29 +180,38 @@ def test_step_kernel_blocks_follow_the_tile():
 def test_step_kernel_blocks_clamp_to_small_tiles():
     """Regression (small-tile clamping): blocks used to silently round UP
     to MIN_KERNEL_BLOCK even when the tile itself was smaller, so a tiny
-    tile got a (64, 64) grid block over mostly-padding rows.  The clamp
-    now follows the tile down to the Pallas f32 sublane floor of 8 and
-    never exceeds the next power of two above the resident extent."""
+    tile got a (64, 64) grid block over mostly-padding rows.  The row clamp
+    follows the tile down to the Pallas f32 sublane floor of 8 and never
+    exceeds the next power of two above the resident extent.  The K block
+    is TPU-aligned: a multiple of 128 lanes, or the whole (padded) K."""
     wl = ConvWorkload(M=256, C=256, P=14, Q=14, R=3, S=3, name="l")
     graph = from_layers([wl], "one")
     step = tiled_plan(graph).steps[0]
-    # rows = P*Q tile = 4, kdim = 8*3*3 = 72: clamp to (8, 64), not (64, 64)
+    # rows = P*Q tile = 4, kdim = 8*3*3 = 72 of K = 2304: the row block
+    # follows the tile to 8, the K block rises from 64 to one lane tile
     tiny = dataclasses.replace(
         step, tiles=(("M", 16), ("C", 8), ("P", 2), ("Q", 2)),
         double_buffer=False, buffer_alloc=())
-    assert step_kernel_blocks(tiny) == (8, MIN_KERNEL_BLOCK)
-    # blocks never exceed the next power of two above the resident extent
+    assert step_kernel_blocks(tiny) == (8, RIR_BLOCK)
+    # a K that fits in the tile's block is taken whole: no lane rounding
+    assert step_kernel_blocks(tiny, k=64) == (8, MIN_KERNEL_BLOCK)
+    assert step_kernel_blocks(tiny, k=16) == (8, MIN_KERNEL_BLOCK)
+    # blocks never exceed the next power of two above the resident extent,
+    # and every K block is lane-aligned or covers K
     for tiles in ((("P", 2), ("Q", 2)), (("M", 8), ("C", 4)),
                   (("C", 8), ("P", 4), ("Q", 4))):
         s = dataclasses.replace(step, tiles=tiles, double_buffer=False,
                                 buffer_alloc=())
-        bm, bk = step_kernel_blocks(s)
-        ext = tile_extents(wl, s.dataflow.with_tiles(tiles))
-        rows = ext["N"] * ext["P"] * ext["Q"]
-        kdim = ext["C"] * wl.R * wl.S
-        assert bm <= max(8, 1 << (rows - 1).bit_length())
-        assert bk <= max(8, 1 << (kdim - 1).bit_length())
-        assert bm >= 8 and bk >= 8
+        for k in (None, 36, 256):
+            bm, bk = step_kernel_blocks(s, k=k)
+            ext = tile_extents(wl, s.dataflow.with_tiles(tiles))
+            rows = ext["N"] * ext["P"] * ext["Q"]
+            kdim = ext["C"] * wl.R * wl.S
+            k_full = wl.C * wl.R * wl.S if k is None else k
+            assert bm <= max(8, 1 << (rows - 1).bit_length())
+            assert bk <= max(RIR_BLOCK, 1 << (kdim - 1).bit_length())
+            assert bk % RIR_BLOCK == 0 or bk >= k_full
+            assert bm >= 8 and bk >= 8
 
 
 def test_tiled_plan_executes_bit_identical_to_untiled():

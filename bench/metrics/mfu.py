@@ -1,0 +1,10 @@
+"""The whole step's share of the chip's peak: useful FLOPs of the requests
+completed in the profiled half of the window, over its length times the
+chip's peak FLOP/s (``peaks.json``)."""
+
+
+def read(run):
+    if run.peak is None or run.served == 0 or run.window_s <= 0:
+        return None
+    flops = run.served * run.work.flops
+    return 100.0 * flops / (run.window_s * run.peak["flops_per_s"])

@@ -11,9 +11,9 @@ admission queue, dynamic batch assembly and background tier upgrades.
 Observability: console output goes through the ``repro.obs`` structured
 logger (``--log-level`` / ``REPRO_LOG``); ``REPRO_TRACE=out.jsonl`` records
 ``serve.plan``/``serve.batch`` spans and the queue/latency histograms
-(``serve.batch_size``, ``serve.time_in_queue_ms``, ``serve.ttft_ms``,
-``serve.prefill_ms``, ``serve.decode_ms_per_token``) for
-``python -m repro.obs.report``.
+(``serve.batch_size``, ``serve.time_in_queue_ms``, ``serve.e2e_ms``,
+``serve.prefill_ms``, ``serve.decode_ms_per_token``) as JSONL; run it under
+a JAX profiler session to see the same spans beside the device operations.
 """
 from __future__ import annotations
 

@@ -3,11 +3,12 @@
 FEATHER's pitch is that per-layer dataflow/layout switching is worth it only
 if the switching overheads are actually negligible; this package is how the
 repro *measures* that instead of asserting it.  It threads through every
-layer of the stack — planner phases, plan-cache traffic, per-step executor
-wall clock (bracketed by ``jax.block_until_ready``, recorded next to the
-step's modeled cycles/energy), the continuous-batching serve loop, the
-training supervisor, and the whole robustness layer whose counters
-``python -m repro.runtime.chaos`` verifies injections against.
+layer of the stack — planner phases, plan-cache traffic, the executor's host
+dispatch of each plan step, each host phase of a served batch (assemble,
+dispatch, split, wait on the device, fetch), the training supervisor, and
+the whole robustness layer whose counters ``python -m repro.runtime.chaos``
+verifies injections against.  No span fences the device: the one place a
+served batch waits for it is its own ``serve.wait`` span.
 
 Every emitted family lives in the ``repro.obs.names`` registry (name +
 label keys); ``repro.check`` lints call sites against it, and the
@@ -83,8 +84,6 @@ Histograms:
       assembled continuous-batch sizes
   ``serve.time_in_queue_ms``
       request wait before batch assembly
-  ``serve.ttft_ms``
-      submit-to-first-output latency
   ``serve.e2e_ms``
       submit-to-completion latency
   ``serve.prefill_ms``
@@ -104,28 +103,42 @@ Spans:
       backtrack/argmin over the DP table
   ``plan_cache.plan``
       cache-wrapped plan resolution
+  ``exec.assemble``
+      stack + zero-pad one batch's request samples
   ``exec.network``
-      whole planned-network execution
+      host dispatch of every layer of one batch
   ``exec.chain``
       one fused-chain dispatch
   ``exec.step``
-      one plan-step kernel dispatch
+      host dispatch of one plan step (step/layer in attrs)
+  ``exec.split``
+      slice one batch's output into per-request outputs
   ``serve.plan``
       engine plan resolution at startup
   ``serve.batch``
       one continuous batch (plan id/tier in attrs)
+  ``serve.wait``
+      host blocked on the device after dispatching a batch
+  ``serve.fetch``
+      device-to-host copies of a batch's request outputs
   ``train.step``
       one traced training step
 -- end generated --
 
-The disabled path is a hard no-op: one module-level flag, no event dicts, no
-string formatting, no timestamps (see ``trace.NULL_SPAN``), so production
-code keeps its instrumentation with tracing off at zero measurable cost.
+The off path is a hard no-op: with the recording off and no profiler
+session collecting, ``span`` returns the shared ``trace.NULL_SPAN`` — no
+event dicts, no string formatting, no timestamps — so production code keeps
+its instrumentation at zero measurable cost.
 
 Capturing a trace
 -----------------
-Set ``REPRO_TRACE`` to a path and run any launcher (they all call
-``configure_from_env``)::
+Under a JAX profiler session (``jax.profiler.start_trace`` or
+``jax.profiler.trace``), every span is also a ``TraceAnnotation`` of its
+name, with its scalar attrs (``step``, ``layer``, ``plan_id``...) as
+metadata, whether or not the JSONL recording is on.
+
+For the JSONL recording, set ``REPRO_TRACE`` to a path and run any launcher
+(they all call ``configure_from_env``)::
 
     REPRO_TRACE=out.jsonl PYTHONPATH=src \\
         python -m repro.launch.serve --arch llama3p2_3b --smoke --plan p.json
@@ -139,21 +152,22 @@ or programmatically::
 
 Reading the trace
 -----------------
-``python -m repro.obs.report out.jsonl`` prints the per-plan-step
-modeled-cycles vs measured-wall-clock table (gap ratios, worst offenders)
-plus planner/cache/serve summaries — the calibration artifact the
-measured-vs-modeled roadmap item asks for.  ``--chrome out.json`` converts
-the same events to Chrome ``trace_event`` format: open it in
-``chrome://tracing`` or https://ui.perfetto.dev to see the span timeline.
-``python -m repro.obs.smoke`` runs a small planned network with tracing on
-and validates the trace schema end-to-end (the CI smoke).
+Open the profiler's ``.xplane.pb`` (under ``<dir>/plugins/profile/``) in
+TensorBoard's profile plugin or in https://ui.perfetto.dev: the program's
+spans sit on the host threads that ran them, on the same timeline as the
+device operations they dispatched, so a gap on the device lines up with the
+host phase that left it idle.  The JSONL keeps each span's duration and
+attrs for scripts (the benchmark's per-layer readers take theirs from it);
+``read_trace`` parses it and ``validate_trace`` checks its schema.
+``python -m repro.obs.smoke`` runs a small planned network with recording
+on and validates the trace end-to-end (the CI smoke).
 """
 from . import names
 from .log import Logger, get_logger, set_level
 from .metrics import (counter_value, gauge_value, hist_samples, hist_stats,
                       inc_counter, observe, registry, set_gauge, snapshot)
-from .trace import (NULL_SPAN, TRACE_SCHEMA, Span, configure_from_env,
-                    disable, enable, enabled, events, export_chrome_trace,
+from .trace import (NULL_SPAN, TRACE_SCHEMA, Span, active,
+                    configure_from_env, disable, enable, enabled, events,
                     flush, measure, now_us, read_trace, record_event,
                     record_span, reset, span, validate_trace)
 
@@ -163,7 +177,7 @@ __all__ = [
     "inc_counter", "set_gauge", "observe", "counter_value", "gauge_value",
     "hist_samples", "hist_stats", "snapshot", "registry",
     "NULL_SPAN", "TRACE_SCHEMA", "Span", "span", "record_span",
-    "record_event", "now_us", "enable", "disable", "enabled", "reset",
-    "events", "flush", "read_trace", "validate_trace",
-    "export_chrome_trace", "configure_from_env", "measure",
+    "record_event", "now_us", "enable", "disable", "enabled", "active",
+    "reset", "events", "flush", "read_trace", "validate_trace",
+    "configure_from_env", "measure",
 ]

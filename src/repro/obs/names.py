@@ -66,7 +66,6 @@ HISTOGRAMS: Dict[str, Tuple[Tuple[str, ...], str]] = {
     "train.step_ms": ((), "traced training-step wall clock"),
     "serve.batch_size": ((), "assembled continuous-batch sizes"),
     "serve.time_in_queue_ms": ((), "request wait before batch assembly"),
-    "serve.ttft_ms": ((), "submit-to-first-output latency"),
     "serve.e2e_ms": ((), "submit-to-completion latency"),
     "serve.prefill_ms": ((), "LM prefill wall clock per batch"),
     "serve.decode_ms_per_token": ((), "LM decode wall clock per token"),
@@ -81,11 +80,15 @@ SPANS: Dict[str, str] = {
     "planner.dp_extend": "DP forward extension over boundaries",
     "planner.argmin": "backtrack/argmin over the DP table",
     "plan_cache.plan": "cache-wrapped plan resolution",
-    "exec.network": "whole planned-network execution",
+    "exec.assemble": "stack + zero-pad one batch's request samples",
+    "exec.network": "host dispatch of every layer of one batch",
     "exec.chain": "one fused-chain dispatch",
-    "exec.step": "one plan-step kernel dispatch",
+    "exec.step": "host dispatch of one plan step (step/layer in attrs)",
+    "exec.split": "slice one batch's output into per-request outputs",
     "serve.plan": "engine plan resolution at startup",
     "serve.batch": "one continuous batch (plan id/tier in attrs)",
+    "serve.wait": "host blocked on the device after dispatching a batch",
+    "serve.fetch": "device-to-host copies of a batch's request outputs",
     "train.step": "one traced training step",
 }
 

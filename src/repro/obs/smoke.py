@@ -3,16 +3,16 @@
     PYTHONPATH=src python -m repro.obs.smoke [--graph tiny|resnet50|mobv3]
         [--out trace.jsonl] [--check-identical]
 
-Plans a network, executes it through the Pallas path with tracing enabled,
-flushes the JSONL trace, validates it against the trace schema, and prints
-the model-vs-measured report.  Exits non-zero on any schema violation or on
-a trace missing the spans the instrumentation promises (planner phases,
-cache counters, one ``exec.step`` per layer).  ``--check-identical``
-additionally re-executes with tracing off and asserts the numeric outputs
-are bit-identical — tracing must observe, never perturb.
+Plans a network, executes it through the Pallas path with the JSONL
+recording on, flushes the trace and validates it against the trace schema.
+Exits non-zero on any schema violation or on a trace missing the spans the
+instrumentation promises (planner phases, cache counters, one ``exec.step``
+per layer).  ``--check-identical`` additionally re-executes with tracing off
+and asserts the numeric outputs are bit-identical — tracing must observe,
+never perturb.
 
 This is the CI tier-1 smoke; the push-to-main job runs it with
-``--graph resnet50`` and uploads the trace artifact next to BENCH_*.json.
+``--graph resnet50`` and uploads the trace next to BENCH_*.json.
 """
 from __future__ import annotations
 
@@ -52,7 +52,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     from repro.api import (EvalConfig, Layout, PlanCache, PlannerOptions,
                            execute_network, plan_network)
     from repro.core.workloads import init_graph_weights
-    from repro.obs.report import build_report, format_report
 
     graph = build_graph(args.graph)
     layouts = tuple(Layout.parse(s) for s in ("HWC_C32", "HWC_H32"))
@@ -107,7 +106,6 @@ def main(argv: Optional[List[str]] = None) -> int:
             print(f"[smoke] FAIL: {err}", file=sys.stderr)
         return 1
 
-    print(format_report(build_report(events)))
     print(f"[smoke] ok: {len(events)} events -> {path} "
           f"(graph={graph.name}, {n_steps} steps"
           + (", outputs bit-identical on/off" if args.check_identical

@@ -1,16 +1,20 @@
 """Zero-dependency tracing: context-manager spans with a strict no-op off path.
 
-One module-level enabled flag gates everything.  When tracing is OFF (the
-default), ``span`` returns a shared singleton whose ``__enter__``/``__exit__``
-do nothing — no event dict is built, no string is formatted, no timestamp is
-read — so instrumented hot paths cost one attribute load + truth test.  When
-ON, every span records ``(name, ts, dur, tid, depth, attrs)`` against a
-process start reference, events accumulate in memory, and ``flush`` writes
-them as JSONL (one event per line, first line a ``meta`` header).  Spans may
-carry *plan provenance* attributes — ``plan_id``, ``graph_hash``,
-``schema_version``, ``step`` — which is what lets the report CLI join a
-measured wall-clock interval back to the plan step whose analytical
-cycles/energy it is supposed to validate.
+A span has two sinks.  The JSONL recording (``enable``/``disable``, one
+module-level flag) keeps ``(name, ts, dur, tid, depth, attrs)`` against a
+process start reference; events accumulate in memory, and ``flush`` writes
+them as JSONL (one event per line, first line a ``meta`` header).  And
+whenever a JAX profiler session is collecting, a span also enters a
+``jax.profiler.TraceAnnotation`` of its name, with its scalar attrs as
+metadata, so the profiler's ``.xplane.pb`` holds the program's spans on its
+own clock, next to the device operations.  The profiler is looked up in
+``sys.modules``, never imported: this package runs without jax.
+
+With both sinks off (the default), ``span`` returns a shared singleton whose
+``__enter__``/``__exit__`` do nothing: no event dict is built, no string is
+formatted, no timestamp is read, so instrumented hot paths cost one flag
+test and the profiler's own ``is_enabled`` check.  Call sites that build an
+attrs dict gate it on ``active()``, which is true when either sink is on.
 
 Event schema (``TRACE_SCHEMA`` = 1), one JSON object per line:
 
@@ -22,13 +26,8 @@ Event schema (``TRACE_SCHEMA`` = 1), one JSON object per line:
 * ``{"ev": "hist", "name": ..., "count": ..., "sum": ..., "min": ..., "max":
   ..., "p50": ..., "p99": ..., "ts": us}``
 
-``export_chrome_trace`` converts the same events to the Chrome
-``trace_event`` JSON array format (spans as ``ph: "X"`` complete events,
-sorted by start time, logs as instants, counters as ``ph: "C"``), loadable in
-``chrome://tracing`` or https://ui.perfetto.dev.
-
 Capture without touching code: ``REPRO_TRACE=out.jsonl`` — the launchers call
-``configure_from_env()``, which enables tracing and registers an atexit
+``configure_from_env()``, which enables recording and registers an atexit
 flush to that path.
 """
 from __future__ import annotations
@@ -37,6 +36,7 @@ import atexit
 import json
 import os
 import pathlib
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -55,8 +55,42 @@ _atexit_registered = False
 
 
 def enabled() -> bool:
-    """True when tracing is recording events (the hot-path gate)."""
+    """True when the JSONL recording is on (gates counters, histograms and
+    JSONL-only work)."""
     return _enabled
+
+
+_annotation_cls = None   # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation`` while a profiler session collects,
+    else None.  Found in ``sys.modules``: a process without jax has no
+    profiler to annotate."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        mod = sys.modules.get("jax.profiler")
+        if mod is None:
+            return None
+        cls = _annotation_cls = mod.TraceAnnotation
+    return cls if cls.is_enabled() else None
+
+
+def active() -> bool:
+    """True when a span goes anywhere: the JSONL recording is on or a JAX
+    profiler session is collecting.  Gate attrs dicts on this."""
+    return _enabled or _annotation() is not None
+
+
+# attr values a profiler annotation can carry as metadata
+_SCALAR = (str, int, float)
+
+
+def _scalars(attrs: Optional[Dict[str, Any]]) -> Dict[str, Any]:
+    if not attrs:
+        return {}
+    return {k: v for k, v in attrs.items() if isinstance(v, _SCALAR)}
 
 
 def _now_us() -> float:
@@ -86,27 +120,44 @@ NULL_SPAN = _NullSpan()
 
 
 class Span:
-    """A live span: times the ``with`` body, records one event on exit."""
+    """A live span: times the ``with`` body and records one JSONL event on
+    exit if ``record``; inside a profiler session it also holds a
+    ``TraceAnnotation`` open over the body."""
 
-    __slots__ = ("name", "attrs", "_start")
+    __slots__ = ("name", "attrs", "_record", "_start", "_ann")
 
-    def __init__(self, name: str, attrs: Optional[Dict[str, Any]]):
+    def __init__(self, name: str, attrs: Optional[Dict[str, Any]],
+                 record: bool = True):
         self.name = name
         self.attrs = attrs
+        self._record = record
+        self._ann = None
 
     def set(self, key: str, value) -> "Span":
         """Attach/overwrite one attribute (usable before or inside the body)."""
         if self.attrs is None:
             self.attrs = {}
         self.attrs[key] = value
+        if self._ann is not None and isinstance(value, _SCALAR):
+            self._ann.set_metadata(**{key: value})
         return self
 
     def __enter__(self) -> "Span":
-        _tls.depth = _depth() + 1
-        self._start = _now_us()
+        cls = _annotation()
+        if cls is not None:
+            self._ann = cls(self.name, **_scalars(self.attrs))
+            self._ann.__enter__()
+        if self._record:
+            _tls.depth = _depth() + 1
+            self._start = _now_us()
         return self
 
     def __exit__(self, *exc) -> bool:
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
+        if not self._record:
+            return False
         end = _now_us()
         _tls.depth = _depth() - 1
         if _enabled:   # disable() between enter/exit drops the event
@@ -121,12 +172,15 @@ class Span:
 def span(name: str, attrs: Optional[Dict[str, Any]] = None):
     """Open a span; ``attrs`` is an optional plain dict of attributes.
 
-    Disabled tracing returns the shared ``NULL_SPAN`` — callers building an
-    expensive attrs dict on a hot path should gate on ``enabled()`` first.
+    With the recording and the profiler both off this returns the shared
+    ``NULL_SPAN`` — callers building an attrs dict on a hot path gate it on
+    ``active()`` first.
     """
-    if not _enabled:
-        return NULL_SPAN
-    return Span(name, attrs)
+    if _enabled:
+        return Span(name, attrs)
+    if _annotation() is not None:
+        return Span(name, attrs, record=False)
+    return NULL_SPAN
 
 
 def record_span(name: str, start_us: float,
@@ -135,7 +189,8 @@ def record_span(name: str, start_us: float,
     """Record an already-timed interval (for paths that cannot use ``with``).
 
     ``start_us``/``end_us`` are ``now_us()`` values; ``end_us`` defaults to
-    the current time.  No-op when disabled.
+    the current time.  JSONL only (the profiler sees nothing); no-op when
+    the recording is off.
     """
     if not _enabled:
         return
@@ -266,42 +321,6 @@ def validate_trace(evs: List[Dict[str, Any]]) -> List[str]:
             if not (e["ts"] >= 0 and e["dur"] >= 0):
                 errors.append(f"line {i}: negative ts/dur")
     return errors
-
-
-# ----------------------------------------------------------- chrome trace_event
-def export_chrome_trace(path: str | os.PathLike,
-                        evs: Optional[List[Dict[str, Any]]] = None,
-                        pid: Optional[int] = None) -> pathlib.Path:
-    """Write events in Chrome ``trace_event`` JSON-array format.
-
-    Spans become ``ph: "X"`` complete events sorted by start timestamp (so
-    ``ts`` is monotonically non-decreasing in the file), log lines become
-    instants, counters/gauges become ``ph: "C"`` counter samples.  Open the
-    result in ``chrome://tracing`` or Perfetto.
-    """
-    evs = _events if evs is None else evs
-    pid = os.getpid() if pid is None else pid
-    out = []
-    for e in evs:
-        kind = e.get("ev")
-        if kind == "span":
-            out.append({"name": e["name"], "cat": "repro", "ph": "X",
-                        "ts": e["ts"], "dur": e["dur"], "pid": pid,
-                        "tid": e.get("tid", 0), "args": e.get("attrs", {})})
-        elif kind == "log":
-            out.append({"name": f"[{e['name']}] {e['msg']}", "cat": "log",
-                        "ph": "i", "s": "t", "ts": e["ts"], "pid": pid,
-                        "tid": e.get("tid", 0),
-                        "args": {"level": e["level"]}})
-        elif kind in ("counter", "gauge"):
-            out.append({"name": e["name"], "cat": "metric", "ph": "C",
-                        "ts": e.get("ts", 0.0), "pid": pid,
-                        "args": {"value": e["value"]}})
-    out.sort(key=lambda d: d["ts"])
-    p = pathlib.Path(path)
-    p.parent.mkdir(parents=True, exist_ok=True)
-    p.write_text(json.dumps(out, indent=1))
-    return p
 
 
 # -------------------------------------------------------------------- measure

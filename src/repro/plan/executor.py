@@ -39,11 +39,10 @@ convolutions and residual joins included — through the same Pallas path:
   (``PlanStep.joins``) is applied, and when the two boundary layouts agree
   the residual add is FUSED into the consumer's ``rir_matmul`` epilogue
   (the kernel's ``residual`` operand) — no separate pass.
-* Fused layer groups (``PlanStep.fused_with``, schema v4) chain the
-  producer's ``rir_matmul`` epilogue straight into the consumer's im2col
-  patch gather: within a group no fence is inserted and no intermediate is
-  forced to materialize in HBM — the group executes (and is measured) as
-  one unit, with the math left bit-identical to the unfused schedule.
+* Fused layer groups (``PlanStep.fused_with``, schema v4) are validated
+  (each member chains into the next layer) and dispatched step by step,
+  with the math bit-identical to the unfused schedule; each step's output
+  is still a separate device array.
 
 All of it validates against the canonical ``execute_network_reference``
 oracle built on ``kernels/ref.py`` conv/depthwise references.
@@ -79,26 +78,12 @@ def _plan_provenance(plan: ExecutionPlan) -> Dict[str, object]:
 
 def _step_attrs(prov: Dict[str, object], i: int, step: PlanStep
                 ) -> Dict[str, object]:
-    """Per-step span attributes: provenance + the step's MODELED numbers.
+    """Per-step span attributes: provenance, the step's index and layer.
 
-    Recording the analytical ``cycles``/``energy_pj`` next to the measured
-    wall-clock (the span's ``dur``) is what makes the trace a calibration
-    artifact: ``repro.obs.report`` computes the model-vs-measured gap per
-    step straight from these events.  ``modeled_stall_cycles`` splits the
-    modeled total into exposed-DRAM-stall vs compute so the gap can be
-    attributed; ``buffer_alloc`` is the per-tensor ping-pong subset the
-    planner chose (empty = uniform split), ``fused_with`` the consumer a
-    fused step chains into without touching HBM.
+    Under a profiler session they ride on the step's annotation, so a host
+    interval in the device trace names the plan step that dispatched it.
     """
-    d = dict(prov)
-    d.update(step=i, layer=step.layer, lowering=step.lowering,
-             reorder=step.reorder, double_buffer=step.double_buffer,
-             modeled_cycles=step.cycles, modeled_energy_pj=step.energy_pj,
-             modeled_stall_cycles=step.dram_stall_cycles,
-             buffer_alloc="+".join(step.buffer_alloc))
-    if step.fused_with is not None:
-        d["fused_with"] = step.fused_with
-    return d
+    return dict(prov, step=i, layer=step.layer)
 
 
 class PlanError(ValueError):
@@ -341,42 +326,36 @@ class PreparedPlan:
                  activation: Optional[Callable[[jax.Array], jax.Array]] = None,
                  use_pallas: bool = True) -> jax.Array:
         plan, block, perms = self.plan, self.block, self.perms
-        # per-step wall-clock needs a sync point per layer, so the traced
-        # path brackets each step with ``jax.block_until_ready`` (values are
-        # untouched — outputs stay bit-identical with tracing on or off);
-        # with tracing off no timestamp is read and no sync is forced
-        traced = obs.enabled()
+        # spans time host dispatch only: nothing here waits for the device
+        active = obs.active()
         with obs.span("exec.chain",
                       dict(self._provenance(), pallas=bool(use_pallas),
-                           rows=int(x.shape[0])) if traced else None):
+                           rows=int(x.shape[0])) if active else None):
             cur = apply_block_perm(x, perms[0], block) \
                 if len(perms[0]) > 1 else x
             for i, (step, w_eff) in enumerate(zip(plan.steps, self.w_eff)):
                 faults.site(faults.EXEC_DISPATCH)
-                if traced:
-                    t0 = obs.now_us()
-                out_perm = perms[i + 1]
-                bm, bk = self.blocks[i]
-                tiled = (cur.shape[0] % bm == 0 and w_eff.shape[0] % bk == 0
-                         and w_eff.shape[1] % block == 0)
-                if use_pallas and tiled and step.kernel == "rir_matmul":
-                    cur = ops.rir_matmul(cur, w_eff, out_perm
-                                         if len(out_perm) > 1 else None,
-                                         block_m=bm, block_n=block,
-                                         block_k=bk)
-                else:
-                    y = jnp.dot(cur, w_eff,
-                                preferred_element_type=jnp.float32)
-                    y = y.astype(cur.dtype)
-                    cur = apply_block_perm(y, out_perm, block) \
-                        if len(out_perm) > 1 else y
-                if activation is not None and i < len(plan.steps) - 1:
-                    # elementwise: commutes with block perms
-                    cur = activation(cur)
-                if traced:
-                    cur = jax.block_until_ready(cur)
-                    obs.record_span("exec.step", t0,
-                                    _step_attrs(self._provenance(), i, step))
+                with obs.span("exec.step", _step_attrs(
+                        self._provenance(), i, step) if active else None):
+                    out_perm = perms[i + 1]
+                    bm, bk = self.blocks[i]
+                    tiled = (cur.shape[0] % bm == 0
+                             and w_eff.shape[0] % bk == 0
+                             and w_eff.shape[1] % block == 0)
+                    if use_pallas and tiled and step.kernel == "rir_matmul":
+                        cur = ops.rir_matmul(cur, w_eff, out_perm
+                                             if len(out_perm) > 1 else None,
+                                             block_m=bm, block_n=block,
+                                             block_k=bk)
+                    else:
+                        y = jnp.dot(cur, w_eff,
+                                    preferred_element_type=jnp.float32)
+                        y = y.astype(cur.dtype)
+                        cur = apply_block_perm(y, out_perm, block) \
+                            if len(out_perm) > 1 else y
+                    if activation is not None and i < len(plan.steps) - 1:
+                        # elementwise: commutes with block perms
+                        cur = activation(cur)
             out = invert_block_perm(cur, perms[-1], block) \
                 if len(perms[-1]) > 1 else cur
         return out
@@ -687,22 +666,13 @@ class PreparedNetwork:
                 block_m=bm, block_k=bk, bias=bias))
         self._buffer_set = set(graph.buffer_sources())
         # fused groups (schema v4): ``fused_with`` chains a step into its
-        # immediate consumer — the intermediate never round-trips HBM, so
-        # the group is fenced (and its wall-clock measured) as ONE unit.
-        # ``_group_start[i]`` is the first member of the group step i
-        # closes; unfused steps are their own group.
+        # immediate consumer, which must be the next layer
         for i, step in enumerate(plan.steps):
             if step.fused_with is not None and step.fused_with != i + 1:
                 raise PlanError(f"step {step.layer}: fused_with="
                                 f"{step.fused_with} is not the next layer")
         if plan.steps and plan.steps[-1].fused_with is not None:
             raise PlanError("last step cannot fuse into a consumer")
-        self._group_start: List[int] = []
-        start = 0
-        for i, step in enumerate(plan.steps):
-            self._group_start.append(start)
-            if step.fused_with is None:
-                start = i + 1
         self._prov: Optional[Dict[str, object]] = None
 
     def _provenance(self) -> Dict[str, object]:
@@ -736,17 +706,18 @@ class PreparedNetwork:
         if not 1 <= k <= n:
             raise PlanError(f"{k} samples for max_batch={n}")
         shp = self.input_shape[1:]
-        arrs = []
-        for i, s in enumerate(samples):
-            a = jnp.asarray(s, jnp.float32)
-            if a.shape != shp:
-                raise PlanError(f"sample {i} shape {a.shape} != planned "
-                                f"per-sample shape {shp}")
-            arrs.append(a)
-        x = jnp.stack(arrs)
-        if k < n:
-            x = jnp.concatenate(
-                [x, jnp.zeros((n - k,) + shp, jnp.float32)])
+        with obs.span("exec.assemble"):
+            arrs = []
+            for i, s in enumerate(samples):
+                a = jnp.asarray(s, jnp.float32)
+                if a.shape != shp:
+                    raise PlanError(f"sample {i} shape {a.shape} != planned "
+                                    f"per-sample shape {shp}")
+                arrs.append(a)
+            x = jnp.stack(arrs)
+            if k < n:
+                x = jnp.concatenate(
+                    [x, jnp.zeros((n - k,) + shp, jnp.float32)])
         return x
 
     def execute_requests(self, samples: Sequence[jax.Array], *,
@@ -755,7 +726,8 @@ class PreparedNetwork:
         """Run a padded request batch; return each request's own output."""
         y = self(self.assemble_batch(samples), activation=activation,
                  use_pallas=use_pallas)
-        return [y[i] for i in range(len(samples))]
+        with obs.span("exec.split"):
+            return [y[i] for i in range(len(samples))]
 
     # ------------------------------------------------------------- execution
     def _join_term(self, st: _NetStep, je: _JoinExec, buf: jax.Array,
@@ -782,14 +754,11 @@ class PreparedNetwork:
                  use_pallas: bool = True) -> jax.Array:
         block = self.block
         N, H, W, C = self.input_shape
-        # traced executions bracket every layer with a device sync and record
-        # the measured wall-clock next to the plan's modeled cycles/energy
-        # (see ``_step_attrs``); values are untouched, so outputs are
-        # bit-identical with tracing on or off
-        traced = obs.enabled()
+        # spans time host dispatch only: nothing here waits for the device
+        active = obs.active()
         with obs.span("exec.network",
                       dict(self._provenance(), batch=int(N),
-                           pallas=bool(use_pallas)) if traced else None):
+                           pallas=bool(use_pallas)) if active else None):
             a = adapt_activation(jnp.asarray(x, jnp.float32), H, W, C)
             if a.shape[0] != N:
                 raise PlanError(f"batch {a.shape[0]} != planned N={N}")
@@ -798,79 +767,68 @@ class PreparedNetwork:
                 cur = apply_block_perm(cur, self.perms[0], block)
             buffers: Dict[int, jax.Array] = {}
             last = len(self.steps) - 1
-            t0 = None
             for i, st in enumerate(self.steps):
                 faults.site(faults.EXEC_DISPATCH)
-                if traced and self._group_start[i] == i:
-                    t0 = obs.now_us()
-                if st.row_map is None:
-                    patches = cur
-                else:
-                    padded = jnp.concatenate(
-                        [cur, jnp.zeros((1, cur.shape[1]), cur.dtype)])
-                    patches = padded[st.row_map].reshape(
-                        st.rows_out, st.k_width)
-                patches = _pad_axis(_pad_axis(patches, st.block_m, 0),
-                                    st.block_k, 1)
-                fused_res = None
-                for je in st.joins:
-                    if not je.fused:
-                        continue
-                    term = buffers[je.src]
-                    fused_res = term if fused_res is None \
-                        else fused_res + term
-                out_perm = st.out_perm if len(st.out_perm) > 1 else None
-                if use_pallas:
-                    res_pad = None
-                    if fused_res is not None:
-                        res_pad = _pad_axis(
-                            _pad_axis(fused_res, st.block_m, 0), block, 1)
-                    y = ops.rir_matmul(patches, st.w_eff, out_perm,
-                                       residual=res_pad, block_m=st.block_m,
-                                       block_n=block, block_k=st.block_k)
-                else:
-                    y = jnp.dot(patches, st.w_eff,
-                                preferred_element_type=jnp.float32)
-                    if out_perm is not None:
-                        y = apply_block_perm(y, out_perm, block)
-                    if fused_res is not None:
-                        y = y + _pad_axis(
-                            _pad_axis(fused_res, st.block_m, 0), block, 1)
-                y = y[:st.rows_out, :st.wl.M]
-                if st.bias is not None:
-                    y = y + st.bias[None, :]
-                for je in st.joins:
-                    if je.fused:
-                        continue
-                    y = y + self._join_term(st, je, buffers[je.src], block)
-                if activation is not None and i < last:
-                    y = activation(y)
-                # a fused step's output stays on device inside the group:
-                # no fence, no span — the group's tail measures the whole
-                # chain (the intermediate never materializes in HBM)
-                if traced and self.plan.steps[i].fused_with is None:
-                    y = jax.block_until_ready(y)
-                    gs = self._group_start[i]
-                    attrs = _step_attrs(self._provenance(), i,
-                                        self.plan.steps[i])
-                    if gs != i:
-                        members = self.plan.steps[gs:i + 1]
-                        attrs.update(
-                            fused_group=f"{gs}-{i}",
-                            modeled_cycles=sum(s.cycles for s in members),
-                            modeled_energy_pj=sum(s.energy_pj
-                                                  for s in members),
-                            modeled_stall_cycles=sum(s.dram_stall_cycles
-                                                     for s in members))
-                    obs.record_span("exec.step", t0, attrs)
+                with obs.span("exec.step", _step_attrs(
+                        self._provenance(), i, self.plan.steps[i])
+                        if active else None):
+                    cur = self._step(st, cur, buffers, block,
+                                     activation if i < last else None,
+                                     use_pallas)
                 if i in self._buffer_set:
-                    buffers[i] = y
-                cur = y
+                    buffers[i] = cur
             out_perm = self.perms[-1]
             if len(out_perm) > 1:
                 cur = invert_block_perm(cur, out_perm, block)
             out = cur.reshape(self.steps[-1].out_shape)
         return out
+
+    def _step(self, st: _NetStep, cur: jax.Array,
+              buffers: Dict[int, jax.Array], block: int,
+              activation: Optional[Callable[[jax.Array], jax.Array]],
+              use_pallas: bool) -> jax.Array:
+        """Dispatch one plan step: gather, pad, kernel, crop, bias, joins."""
+        if st.row_map is None:
+            patches = cur
+        else:
+            padded = jnp.concatenate(
+                [cur, jnp.zeros((1, cur.shape[1]), cur.dtype)])
+            patches = padded[st.row_map].reshape(st.rows_out, st.k_width)
+        patches = _pad_axis(_pad_axis(patches, st.block_m, 0),
+                            st.block_k, 1)
+        fused_res = None
+        for je in st.joins:
+            if not je.fused:
+                continue
+            term = buffers[je.src]
+            fused_res = term if fused_res is None else fused_res + term
+        out_perm = st.out_perm if len(st.out_perm) > 1 else None
+        if use_pallas:
+            res_pad = None
+            if fused_res is not None:
+                res_pad = _pad_axis(
+                    _pad_axis(fused_res, st.block_m, 0), block, 1)
+            y = ops.rir_matmul(patches, st.w_eff, out_perm,
+                               residual=res_pad, block_m=st.block_m,
+                               block_n=block, block_k=st.block_k)
+        else:
+            y = jnp.dot(patches, st.w_eff,
+                        preferred_element_type=jnp.float32)
+            if out_perm is not None:
+                y = apply_block_perm(y, out_perm, block)
+            if fused_res is not None:
+                y = y + _pad_axis(
+                    _pad_axis(fused_res, st.block_m, 0), block, 1)
+        y = y[:st.rows_out, :st.wl.M]
+        if st.bias is not None:
+            y = y + st.bias[None, :]
+        for je in st.joins:
+            if je.fused:
+                continue
+            y = y + self._join_term(st, je, buffers[je.src], block)
+        if activation is not None:
+            y = activation(y)
+        return y
 
 
 def prepare_network(plan: ExecutionPlan, graph: LayerGraph,

@@ -534,7 +534,7 @@ class NetworkPlanner:
                 if greedy.key < best.key:
                     best = greedy
             plan = self._to_plan(best, "network-dp")
-            if obs.enabled():   # plan_id hashes; don't compute it when off
+            if obs.active():   # plan_id hashes; don't compute it when off
                 root.set("graph_hash", plan.graph_hash) \
                     .set("plan_id", plan.plan_id) \
                     .set("total_cycles", plan.total_cycles)

@@ -22,10 +22,13 @@ Backpressure is a *typed* contract: a full queue (or an injected
 ``serve.queue`` admission fault — the chaos schedule's new site) rejects
 with ``QueueFullError`` immediately; admission never blocks and never
 deadlocks.  Observability: ``serve.queue_depth`` gauge,
-``serve.batch_size`` / ``serve.time_in_queue_ms`` / ``serve.ttft_ms`` /
-``serve.e2e_ms`` histograms, ``serve.requests`` / ``serve.rejected{reason=}``
-/ ``serve.batches`` / ``serve.plan_upgrade`` counters, and a ``serve.batch``
-span carrying ``plan_id`` / ``plan_tier`` / ``plan_reason``.
+``serve.batch_size`` / ``serve.time_in_queue_ms`` / ``serve.e2e_ms``
+histograms, ``serve.requests`` / ``serve.rejected{reason=}`` /
+``serve.batches`` / ``serve.plan_upgrade`` counters, and a ``serve.batch``
+span carrying ``plan_id`` / ``plan_tier`` / ``plan_reason``.  Inside it, a
+network batch's host phases are spans of their own: ``exec.assemble``,
+``exec.network`` (dispatch), ``exec.split``, ``serve.wait`` (the host
+blocked on the device) and ``serve.fetch`` (device-to-host copies).
 """
 from __future__ import annotations
 
@@ -143,8 +146,10 @@ class _NetworkBackend:
         import jax
         outs = prepared.execute_requests(
             payloads, use_pallas=self.config.use_pallas)
-        outs = [np.asarray(o) for o in jax.block_until_ready(outs)]
-        return outs
+        with obs.span("serve.wait"):
+            outs = jax.block_until_ready(outs)
+        with obs.span("serve.fetch"):
+            return [np.asarray(o) for o in outs]
 
     def upgraded(self, resolved):
         """Build the tier-1 prepared network for an upgraded plan."""
@@ -478,7 +483,7 @@ class ServeEngine:
                 obs.observe("serve.time_in_queue_ms",
                             (t_asm - t.submit_us) / 1e3)
         attrs = None
-        if traced:
+        if obs.active():
             attrs = {"batch": len(batch)}
             if resolved is not None:
                 attrs.update(plan_id=resolved.plan.plan_id,
@@ -500,9 +505,6 @@ class ServeEngine:
         for t, out in zip(batch, outs):
             t._resolve(value=out)
             if traced:
-                # one model pass yields each request's first (and, for the
-                # network backend, only) output token/tensor
-                obs.observe("serve.ttft_ms", (done - t.submit_us) / 1e3)
                 obs.observe("serve.e2e_ms", (done - t.submit_us) / 1e3)
 
     # ---------------------------------------------------------- tier upgrade

@@ -1,5 +1,6 @@
-"""repro.obs — tracing, metrics, report, and the zero-overhead off path."""
-import json
+"""repro.obs — tracing, metrics, profiler annotations, and the
+zero-overhead off path."""
+import dataclasses
 import time
 
 import numpy as np
@@ -13,7 +14,6 @@ from repro.core.layout import Layout
 from repro.core.layoutloop import EvalConfig
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
-from repro.obs.report import build_report, format_report, step_rows
 from repro.plan import (NetworkPlanner, PlannerOptions, execute_network,
                         from_layers)
 
@@ -141,26 +141,6 @@ def test_validate_trace_catches_violations():
     assert any("unknown event kind" in e for e in errs)
 
 
-def test_chrome_export_parses_and_ts_monotonic(tmp_path, obs_enabled):
-    with obs.span("a"):
-        with obs.span("b"):
-            pass
-    with obs.span("c"):
-        pass
-    obs.get_logger("t").warning("note")
-    obs.inc_counter("cnt")
-    evs = list(obs.events()) + obs_metrics.snapshot_events(obs.now_us())
-    p = obs.export_chrome_trace(tmp_path / "c.json", evs)
-    chrome = json.loads(p.read_text())
-    assert isinstance(chrome, list) and chrome
-    ts = [e["ts"] for e in chrome]
-    assert ts == sorted(ts), "chrome events not sorted by ts"
-    phases = {e["ph"] for e in chrome}
-    assert {"X", "i", "C"} <= phases
-    for e in chrome:
-        assert {"name", "ph", "ts", "pid"} <= set(e)
-
-
 # ------------------------------------------------------- disabled == no-op
 def test_disabled_path_allocates_no_events(obs_reset):
     assert not obs.enabled()
@@ -207,6 +187,75 @@ def test_reset_clears_state():
     assert obs.counter_value("c") == 0.0
 
 
+# ------------------------------------------------ profiler annotations
+def _profile(tmp_path, body):
+    """Run ``body`` under a JAX profiler session; return its ProfileData."""
+    import jax
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (xplane,) = tmp_path.rglob("*.xplane.pb")
+    return jax.profiler.ProfileData.from_file(str(xplane))
+
+
+def _host_events(profile, name):
+    return [ev for plane in profile.planes if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events if ev.name == name]
+
+
+def test_span_reaches_the_profiler_with_recording_off(tmp_path, obs_reset):
+    import jax
+
+    def body():
+        with jax.profiler.TraceAnnotation("outer"):
+            with obs.span("exec.step", {"step": 3, "layer": "conv1",
+                                        "shape": (2, 2)}) as sp:
+                sp.set("extra", 5)
+
+    prof = _profile(tmp_path, body)
+    assert obs.events() == [], "the JSONL recording was off"
+    (outer,) = _host_events(prof, "outer")
+    (ev,) = _host_events(prof, "exec.step")
+    stats = dict(ev.stats)
+    assert stats["step"] == 3 and stats["layer"] == "conv1"
+    assert stats["extra"] == 5
+    assert "shape" not in stats, "only scalar attrs ride along"
+    assert outer.start_ns <= ev.start_ns
+    assert ev.start_ns + ev.duration_ns <= outer.start_ns + outer.duration_ns
+
+
+def test_span_records_and_annotates_when_both_on(tmp_path, obs_enabled):
+    def body():
+        with obs.span("serve.batch", {"batch": 2}):
+            with obs.span("serve.wait"):
+                pass
+
+    prof = _profile(tmp_path, body)
+    assert [e["name"] for e in obs.events()] == ["serve.wait", "serve.batch"]
+    assert [e["depth"] for e in obs.events()] == [1, 0]
+    (batch,) = _host_events(prof, "serve.batch")
+    (wait,) = _host_events(prof, "serve.wait")
+    assert dict(batch.stats)["batch"] == 2
+    assert batch.start_ns <= wait.start_ns
+    assert obs_trace._depth() == 0
+
+
+def test_span_is_null_again_once_the_profiler_stops(tmp_path, obs_reset):
+    seen = {}
+
+    def body():
+        seen["span"] = obs.span("x")
+        seen["active"] = obs.active()
+
+    _profile(tmp_path, body)
+    assert seen["span"] is not obs.NULL_SPAN and seen["active"]
+    assert not obs.enabled() and not obs.active()
+    assert obs.span("x", {"a": 1}) is obs.NULL_SPAN
+    assert len(obs.events()) == 0
+
+
 # ------------------------------------------------------------------ measure
 def test_measure_blocks_and_returns_result(obs_reset):
     import jax
@@ -247,59 +296,37 @@ def test_execute_network_bit_identical_and_traced(obs_reset):
         assert a["graph_hash"] == plan.graph_hash
         assert a["schema_version"] == plan.version
         assert a["step"] == i
-        assert a["modeled_cycles"] == plan.steps[i].cycles
-        assert a["modeled_energy_pj"] == plan.steps[i].energy_pj
+        assert a["layer"] == plan.steps[i].layer
     assert nets[0]["attrs"]["plan_id"] == plan.plan_id
 
 
-# ------------------------------------------------------------------- report
-def _synthetic_exec_events():
-    mk = lambda step, cyc, dur: {
-        "ev": "span", "name": "exec.step", "ts": 10.0 * step, "dur": dur,
-        "tid": 0, "depth": 1,
-        "attrs": {"plan_id": "p0", "graph_hash": "g", "schema_version": 3,
-                  "graph": "tiny", "step": step, "layer": f"l{step}",
-                  "lowering": "gemm", "reorder": "rir",
-                  "double_buffer": False, "modeled_cycles": cyc,
-                  "modeled_energy_pj": 1.0}}
-    return [{"ev": "meta", "schema": 1, "pid": 1, "unix_time": 0.0},
-            mk(0, 1000.0, 2000.0), mk(1, 1000.0, 1000.0),
-            mk(1, 1000.0, 3000.0)]   # step 1 executed twice -> averaged
-
-
-def test_report_gap_ratios_and_aggregation():
-    rows = step_rows(_synthetic_exec_events(), freq_ghz=1.0)
-    assert len(rows) == 2
-    r0, r1 = rows
-    assert r0["modeled_us"] == 1.0 and r0["measured_us"] == 2000.0
-    assert r0["gap"] == pytest.approx(2000.0)
-    assert r1["runs"] == 2 and r1["measured_us"] == 2000.0
-    # both gaps equal -> rel normalizes to 1.0
-    assert r0["rel_gap"] == pytest.approx(1.0)
-    rep = build_report(_synthetic_exec_events())
-    assert rep["totals"]["executions"] == 3
-    assert rep["worst"][0]["gap"] >= rep["worst"][-1]["gap"]
-    text = format_report(rep)
-    assert "modeled vs measured" in text and "l0" in text
-    assert "worst offenders" in text
-
-
-def test_report_on_real_traced_execution(tmp_path, obs_reset):
-    graph = tiny_graph(2)
+def test_traced_execute_network_never_fences(obs_reset, monkeypatch):
+    """Spans time host dispatch: no ``block_until_ready`` anywhere in a
+    traced execution, and one ``exec.step`` per plan step, fused or not."""
+    import jax
+    graph = tiny_graph(3)
     plan = tiny_plan(graph)
+    plan = dataclasses.replace(plan, steps=(
+        dataclasses.replace(plan.steps[0], fused_with=1),) + plan.steps[1:])
     from repro.core.workloads import init_graph_weights
     ws = init_graph_weights(list(graph.layers), seed=0)
-    x = jnp.zeros(graph.input_shape(), jnp.float32)
+    rng = np.random.default_rng(1)
+    x = jnp.asarray(rng.normal(size=graph.input_shape()), jnp.float32)
+    y_off = np.asarray(execute_network(plan, graph, x, ws))
+
+    fences = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda v: fences.append(1) or real(v))
     obs.enable()
-    execute_network(plan, graph, x, ws)
-    p = obs.flush(tmp_path / "t.jsonl")
-    obs.reset()
-    evs = obs.read_trace(p)
-    assert obs.validate_trace(evs) == []
-    rep = build_report(evs)
-    assert len(rep["steps"]) == len(plan.steps)
-    assert all(r["gap"] > 0 for r in rep["steps"])
-    assert rep["steps"][0]["plan_id"] == plan.plan_id
+    y_on = execute_network(plan, graph, x, ws)
+    monkeypatch.undo()
+    assert fences == []
+    steps = [e for e in obs.events() if e["name"] == "exec.step"]
+    assert [e["attrs"]["step"] for e in steps] == list(range(len(plan.steps)))
+    assert [e["attrs"]["layer"] for e in steps] == \
+        [s.layer for s in plan.steps]
+    assert (np.asarray(y_on) == y_off).all()
 
 
 # ------------------------------------------------------------------ planner

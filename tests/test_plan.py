@@ -269,6 +269,37 @@ def test_prepared_plan_reuse_matches_per_call_setup():
         np.testing.assert_allclose(y_prep, y_plain, rtol=1e-4, atol=0.1)
 
 
+def test_traced_prepared_plan_never_fences(obs_enabled, monkeypatch):
+    """A traced GEMM chain waits for the device nowhere and emits one
+    ``exec.step`` per plan step; its output is the untraced one's."""
+    import jax
+    opts = PlannerOptions(switch_modes=("rir",), layouts=SMALL_LAYOUTS,
+                          parallel_dims=("C", "P", "Q"))
+    plan = NetworkPlanner(gemm_chain(), EvalConfig(), opts).plan()
+    rng = np.random.default_rng(5)
+    ws = [jnp.asarray(rng.normal(size=(256, 384)), jnp.float32),
+          jnp.asarray(rng.normal(size=(384, 512)), jnp.float32),
+          jnp.asarray(rng.normal(size=(512, 256)), jnp.float32)]
+    x = jnp.asarray(rng.normal(size=(128, 256)), jnp.float32)
+    prepared = prepare_plan(plan, 256, ws)
+    obs.disable()
+    y_off = np.asarray(prepared(x))
+    obs.reset()
+    obs.enable()
+    fences = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda v: fences.append(1) or real(v))
+    y_on = prepared(x)
+    monkeypatch.undo()
+    assert fences == []
+    steps = [e for e in obs.events() if e["name"] == "exec.step"]
+    assert [e["attrs"]["step"] for e in steps] == list(range(len(plan.steps)))
+    (chain,) = [e for e in obs.events() if e["name"] == "exec.chain"]
+    assert all(chain["ts"] <= e["ts"] for e in steps)
+    np.testing.assert_array_equal(np.asarray(y_on), y_off)
+
+
 def test_stale_prepared_plan_rejected():
     """prepared= built from different weights/plan must fail loudly, not
     silently compute with the old pre-permuted weights."""

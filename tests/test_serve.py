@@ -267,7 +267,30 @@ def test_serve_batch_span_carries_plan_attrs(cache):
     assert attrs["plan_tier"] in ("cached", "replanned")
     assert attrs["plan_reason"] == ""
     assert obs.counter_value("serve.batches") >= 1
-    assert len(obs.hist_samples("serve.ttft_ms")) >= 2
+    assert len(obs.hist_samples("serve.e2e_ms")) >= 2
+
+
+def test_served_batch_host_phases_nest_in_serve_batch(cache):
+    """One served batch emits each host phase once, in order, on the
+    worker's thread and inside that batch's ``serve.batch`` interval."""
+    cfg = ServeConfig(graph="tiny", max_batch=2, workers=1)
+    with ServeEngine(cfg, cache=cache) as eng:
+        obs.reset()
+        obs.enable()
+        eng.serve(_samples(eng, 1))
+    spans = [e for e in obs.events() if e.get("ev") == "span"]
+    (batch,) = [e for e in spans if e["name"] == "serve.batch"]
+    phases = ("exec.assemble", "exec.network", "exec.split", "serve.wait",
+              "serve.fetch")
+    got = [e for e in spans if e["name"] in phases]
+    assert sorted(e["name"] for e in got) == sorted(phases)
+    assert [e["name"] for e in sorted(got, key=lambda e: e["ts"])] == \
+        list(phases)
+    end = batch["ts"] + batch["dur"]
+    for e in got:
+        assert e["tid"] == batch["tid"]
+        assert e["depth"] > batch["depth"]
+        assert batch["ts"] <= e["ts"] and e["ts"] + e["dur"] <= end
 
 
 # -------------------------------------------------------------- facade

@@ -106,11 +106,11 @@ Spans:
   ``exec.assemble``
       stack + zero-pad one batch's request samples
   ``exec.network``
-      host dispatch of every layer of one batch
+      host dispatch of one batch's network program
   ``exec.chain``
-      one fused-chain dispatch
+      host dispatch of one GEMM-chain program
   ``exec.step``
-      host dispatch of one plan step (step/layer in attrs)
+      one plan step's named scope in the program (exec.step:<i>:<layer>), not a host span
   ``exec.split``
       slice one batch's output into per-request outputs
   ``serve.plan``

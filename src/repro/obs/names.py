@@ -81,9 +81,10 @@ SPANS: Dict[str, str] = {
     "planner.argmin": "backtrack/argmin over the DP table",
     "plan_cache.plan": "cache-wrapped plan resolution",
     "exec.assemble": "stack + zero-pad one batch's request samples",
-    "exec.network": "host dispatch of every layer of one batch",
-    "exec.chain": "one fused-chain dispatch",
-    "exec.step": "host dispatch of one plan step (step/layer in attrs)",
+    "exec.network": "host dispatch of one batch's network program",
+    "exec.chain": "host dispatch of one GEMM-chain program",
+    "exec.step": "one plan step's named scope in the program "
+                 "(exec.step:<i>:<layer>), not a host span",
     "exec.split": "slice one batch's output into per-request outputs",
     "serve.plan": "engine plan resolution at startup",
     "serve.batch": "one continuous batch (plan id/tier in attrs)",

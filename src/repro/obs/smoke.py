@@ -6,8 +6,9 @@
 Plans a network, executes it through the Pallas path with the JSONL
 recording on, flushes the trace and validates it against the trace schema.
 Exits non-zero on any schema violation or on a trace missing the spans the
-instrumentation promises (planner phases, cache counters, one ``exec.step``
-per layer).  ``--check-identical`` additionally re-executes with tracing off
+instrumentation promises (planner phases, cache counters, the
+``exec.network`` dispatch), and on a program that lacks one ``exec.step``
+scope per layer, in order.  ``--check-identical`` additionally re-executes with tracing off
 and asserts the numeric outputs are bit-identical — tracing must observe,
 never perturb.
 
@@ -50,7 +51,8 @@ def main(argv: Optional[List[str]] = None) -> int:
 
     from repro import obs
     from repro.api import (EvalConfig, Layout, PlanCache, PlannerOptions,
-                           execute_network, plan_network)
+                           execute_network, plan_network, prepare_network)
+    from repro.plan.executor import step_scopes
     from repro.core.workloads import init_graph_weights
 
     graph = build_graph(args.graph)
@@ -88,8 +90,10 @@ def main(argv: Optional[List[str]] = None) -> int:
     errors = obs.validate_trace(events)
     spans = {e["name"] for e in events if e.get("ev") == "span"}
     counters = {e["name"] for e in events if e.get("ev") == "counter"}
-    n_steps = sum(1 for e in events
-                  if e.get("ev") == "span" and e["name"] == "exec.step")
+    prepared = prepare_network(plan, graph, ws)
+    scopes = step_scopes(prepared.program().lower(
+        prepared.arrays, x).as_text(debug_info=True))
+    n_steps = len(scopes)
     for want in ("planner.plan", "planner.lattice_build", "planner.dp_extend",
                  "planner.argmin", "exec.network", "plan_cache.plan"):
         if want not in spans:
@@ -98,8 +102,8 @@ def main(argv: Optional[List[str]] = None) -> int:
                  "planner.lattice_builds"):
         if want not in counters:
             errors.append(f"missing counter {want!r}")
-    if n_steps != len(plan.steps):
-        errors.append(f"{n_steps} exec.step spans for "
+    if scopes != list(range(len(plan.steps))):
+        errors.append(f"exec.step scopes {scopes} for "
                       f"{len(plan.steps)}-step plan")
     if errors:
         for err in errors:

@@ -40,9 +40,16 @@ convolutions and residual joins included — through the same Pallas path:
   the residual add is FUSED into the consumer's ``rir_matmul`` epilogue
   (the kernel's ``residual`` operand) — no separate pass.
 * Fused layer groups (``PlanStep.fused_with``, schema v4) are validated
-  (each member chains into the next layer) and dispatched step by step,
-  with the math bit-identical to the unfused schedule; each step's output
-  is still a separate device array.
+  (each member chains into the next layer) and run step by step, with the
+  math bit-identical to the unfused schedule; each step's output is still
+  written to HBM by its own ``pallas_call``.
+
+Both prepared executors run a batch as ONE jitted program per (plan, batch
+shape): the whole per-batch forward is traced once, the prepared arrays
+(effective weights, row maps, biases) reach it as an argument rather than
+as constants of the program, and each plan step's operations sit under a
+``jax.named_scope`` named ``exec.step:<index>:<layer>``, so the device
+trace can attribute them.  The host dispatches the program once per batch.
 
 All of it validates against the canonical ``execute_network_reference``
 oracle built on ``kernels/ref.py`` conv/depthwise references.
@@ -51,7 +58,10 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+import re
+import threading
+from typing import (Callable, Dict, List, NamedTuple, Optional,
+                    Sequence, Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -76,14 +86,74 @@ def _plan_provenance(plan: ExecutionPlan) -> Dict[str, object]:
             "schema_version": plan.version, "graph": plan.graph_name}
 
 
-def _step_attrs(prov: Dict[str, object], i: int, step: PlanStep
-                ) -> Dict[str, object]:
-    """Per-step span attributes: provenance, the step's index and layer.
+def step_scope(i: int, step: PlanStep) -> str:
+    """Name of plan step ``i``'s ``jax.named_scope`` inside the program.
 
-    Under a profiler session they ride on the step's annotation, so a host
-    interval in the device trace names the plan step that dispatched it.
+    It prefixes the ``op_name`` of every operation the step traces, so the
+    device trace (and the lowered program's locations) name the plan step
+    each operation belongs to.
     """
-    return dict(prov, step=i, layer=step.layer)
+    return f"exec.step:{i}:{step.layer}"
+
+
+_STEP_SCOPE = re.compile(r"exec\.step:(\d+):")
+
+
+def step_scopes(program_text: str) -> List[int]:
+    """Plan-step indices of the ``exec.step`` scopes that a program's text
+    names (``Lowered.as_text(debug_info=True)``), in order of appearance."""
+    return list(dict.fromkeys(int(m) for m in _STEP_SCOPE.findall(
+        program_text)))
+
+
+_PROGRAMS_LOCK = threading.Lock()
+
+
+class _Program:
+    """A prepared executor's per-batch forward as one jitted program.
+
+    Subclasses define ``_forward(arrays, x, activation, use_pallas)``, which
+    traces every step, and ``arrays``, the pytree of prepared device arrays
+    it reads: they enter the program as its first argument, never as
+    constants baked into it.  One ``jax.jit`` is built per ``(activation,
+    use_pallas)`` on first use and kept; JAX compiles it once per input
+    shape, and the serving path always pads to the plan's batch.
+    """
+
+    _PROGRAM: str                # the compiled module is ``jit_<_PROGRAM>``
+    plan: ExecutionPlan
+    arrays: object
+    _programs: Dict[tuple, Callable]
+    _prov: Optional[Dict[str, object]]
+
+    def _provenance(self) -> Dict[str, object]:
+        if self._prov is None:
+            self._prov = _plan_provenance(self.plan)
+        return self._prov
+
+    def program(self, activation: Optional[Callable] = None,
+                use_pallas: bool = True) -> Callable:
+        """The jitted ``f(arrays, x)`` for this activation and kernel path."""
+        key = (activation, bool(use_pallas))
+        # serve workers share one instance: one jit per key, or each
+        # worker's first batch would compile a program of its own
+        with _PROGRAMS_LOCK:
+            fn = self._programs.get(key)
+            if fn is None:
+                def forward(arrays, x):
+                    return self._forward(arrays, x, *key)
+                forward.__name__ = self._PROGRAM
+                fn = self._programs[key] = jax.jit(forward)
+        return fn
+
+    def _dispatch(self, span: str, attrs: Optional[Dict[str, object]],
+                  x: jax.Array, activation: Optional[Callable],
+                  use_pallas: bool) -> jax.Array:
+        """One host dispatch of the program; the span waits for no device
+        work, and the ``exec.dispatch`` fault site fires once per call."""
+        with obs.span(span, attrs):
+            faults.site(faults.EXEC_DISPATCH)
+            return self.program(activation, use_pallas)(self.arrays, x)
 
 
 class PlanError(ValueError):
@@ -284,14 +354,17 @@ def _boundary_perms(plan: ExecutionPlan, x_dim: int,
         plan, [x_dim] + [w.shape[1] for w in weights], block)
 
 
-class PreparedPlan:
+class PreparedPlan(_Program):
     """Everything ``execute_plan`` derives from ``(plan, shapes)`` alone.
 
     Boundary perms, gather indices, and the pre-permuted (effective) weight
     matrices are computed once here; calling the object runs only the
-    per-batch matmul chain.  Reuse one instance across ``execute_plan`` calls
-    that share the plan and weights (e.g. every serving batch).
+    per-batch matmul chain, as one jitted program.  Reuse one instance
+    across ``execute_plan`` calls that share the plan and weights (e.g.
+    every serving batch).
     """
+
+    _PROGRAM = "exec_chain"
 
     def __init__(self, plan: ExecutionPlan, x_dim: int,
                  weights: Sequence[jax.Array], *, block: int = RIR_BLOCK):
@@ -311,54 +384,48 @@ class PreparedPlan:
         # per-step kernel blocking, derived from the plan's tiling
         self.blocks = [step_kernel_blocks(s, block, k=w.shape[0])
                        for s, w in zip(plan.steps, weights)]
-        self.w_eff = [
+        # the effective weights: the program's argument
+        self.arrays = tuple(
             permute_weight_blocks(w, self.perms[i], block)
             if len(self.perms[i]) > 1 else w
-            for i, w in enumerate(weights)]
-        self._prov: Optional[Dict[str, object]] = None
-
-    def _provenance(self) -> Dict[str, object]:
-        if self._prov is None:
-            self._prov = _plan_provenance(self.plan)
-        return self._prov
+            for i, w in enumerate(weights))
+        self._programs = {}
+        self._prov = None
 
     def __call__(self, x: jax.Array, *,
                  activation: Optional[Callable[[jax.Array], jax.Array]] = None,
                  use_pallas: bool = True) -> jax.Array:
+        attrs = dict(self._provenance(), pallas=bool(use_pallas),
+                     rows=int(x.shape[0])) if obs.active() else None
+        return self._dispatch("exec.chain", attrs, x, activation, use_pallas)
+
+    def _forward(self, arrays, x, activation, use_pallas):
         plan, block, perms = self.plan, self.block, self.perms
-        # spans time host dispatch only: nothing here waits for the device
-        active = obs.active()
-        with obs.span("exec.chain",
-                      dict(self._provenance(), pallas=bool(use_pallas),
-                           rows=int(x.shape[0])) if active else None):
-            cur = apply_block_perm(x, perms[0], block) \
-                if len(perms[0]) > 1 else x
-            for i, (step, w_eff) in enumerate(zip(plan.steps, self.w_eff)):
-                faults.site(faults.EXEC_DISPATCH)
-                with obs.span("exec.step", _step_attrs(
-                        self._provenance(), i, step) if active else None):
-                    out_perm = perms[i + 1]
-                    bm, bk = self.blocks[i]
-                    tiled = (cur.shape[0] % bm == 0
-                             and w_eff.shape[0] % bk == 0
-                             and w_eff.shape[1] % block == 0)
-                    if use_pallas and tiled and step.kernel == "rir_matmul":
-                        cur = ops.rir_matmul(cur, w_eff, out_perm
-                                             if len(out_perm) > 1 else None,
-                                             block_m=bm, block_n=block,
-                                             block_k=bk)
-                    else:
-                        y = jnp.dot(cur, w_eff,
-                                    preferred_element_type=jnp.float32)
-                        y = y.astype(cur.dtype)
-                        cur = apply_block_perm(y, out_perm, block) \
-                            if len(out_perm) > 1 else y
-                    if activation is not None and i < len(plan.steps) - 1:
-                        # elementwise: commutes with block perms
-                        cur = activation(cur)
-            out = invert_block_perm(cur, perms[-1], block) \
-                if len(perms[-1]) > 1 else cur
-        return out
+        cur = apply_block_perm(x, perms[0], block) \
+            if len(perms[0]) > 1 else x
+        for i, (step, w_eff) in enumerate(zip(plan.steps, arrays)):
+            with jax.named_scope(step_scope(i, step)):
+                out_perm = perms[i + 1]
+                bm, bk = self.blocks[i]
+                tiled = (cur.shape[0] % bm == 0
+                         and w_eff.shape[0] % bk == 0
+                         and w_eff.shape[1] % block == 0)
+                if use_pallas and tiled and step.kernel == "rir_matmul":
+                    cur = ops.rir_matmul(cur, w_eff, out_perm
+                                         if len(out_perm) > 1 else None,
+                                         block_m=bm, block_n=block,
+                                         block_k=bk)
+                else:
+                    y = jnp.dot(cur, w_eff,
+                                preferred_element_type=jnp.float32)
+                    y = y.astype(cur.dtype)
+                    cur = apply_block_perm(y, out_perm, block) \
+                        if len(out_perm) > 1 else y
+                if activation is not None and i < len(plan.steps) - 1:
+                    # elementwise: commutes with block perms
+                    cur = activation(cur)
+        return invert_block_perm(cur, perms[-1], block) \
+            if len(perms[-1]) > 1 else cur
 
 
 def prepare_plan(plan: ExecutionPlan, x_dim: int,
@@ -558,11 +625,11 @@ class _JoinExec:
 
 @dataclasses.dataclass
 class _NetStep:
-    """Everything layer execution needs, derived once at prepare time."""
+    """The static half of one layer's execution, derived at prepare time:
+    shapes, blocks, perms and the join strategy (its arrays are the
+    step's ``_StepArrays``)."""
 
     wl: object
-    row_map: Optional[jax.Array]   # None = pure GEMM passthrough
-    w_eff: jax.Array               # (K_pad, M_pad) kernel-ready weight
     k_width: int                   # taps * in_width (pre-pad)
     rows_out: int
     out_perm: Tuple[int, ...]
@@ -570,17 +637,26 @@ class _NetStep:
     out_shape: Tuple[int, int, int, int]       # (N, P, Q, M)
     block_m: int = RIR_BLOCK       # kernel grid blocks from the plan's tile
     block_k: int = RIR_BLOCK
-    bias: Optional[jax.Array] = None   # (M,), stored in out_perm block order
 
 
-class PreparedNetwork:
+class _StepArrays(NamedTuple):
+    """One layer's prepared device arrays: an argument of the program."""
+
+    row_map: Optional[jax.Array]   # (rows_out, taps) int32; None = no gather
+    w_eff: jax.Array               # (K_pad, M_pad) kernel-ready weight
+    bias: Optional[jax.Array]      # (M,), stored in out_perm block order
+
+
+class PreparedNetwork(_Program):
     """``execute_network``'s per-(plan, graph, weights) setup, hoisted.
 
     Derives every boundary's block permutation, every layer's fused
     (adapter ∘ im2col) patch-gather row map, the layout-aligned effective
     weights, and the resolved join strategy — so a serving loop pays only
-    the per-batch gathers and matmuls.
+    the per-batch gathers and matmuls, dispatched as one jitted program.
     """
+
+    _PROGRAM = "exec_network"
 
     def __init__(self, plan: ExecutionPlan, graph: LayerGraph,
                  weights: Sequence[jax.Array], *, block: int = RIR_BLOCK,
@@ -614,6 +690,7 @@ class PreparedNetwork:
             _derive_boundary_perms(plan, widths, block)
 
         self.steps: List[_NetStep] = []
+        arrays: List[_StepArrays] = []
         for i, (step, wl, w) in enumerate(zip(plan.steps, graph.layers,
                                               weights)):
             in_width = widths[i]
@@ -659,11 +736,14 @@ class PreparedNetwork:
                     src=src, fused=fused, src_perm=self.perms[src + 1],
                     src_shape=(swl.N, swl.P, swl.Q, swl.M)))
             self.steps.append(_NetStep(
-                wl=wl, row_map=row_map, w_eff=w_eff,
-                k_width=wl.R * wl.S * in_width, rows_out=rows_out,
+                wl=wl, k_width=wl.R * wl.S * in_width, rows_out=rows_out,
                 out_perm=out_perm, joins=tuple(joins),
                 out_shape=(wl.N, wl.P, wl.Q, wl.M),
-                block_m=bm, block_k=bk, bias=bias))
+                block_m=bm, block_k=bk))
+            arrays.append(_StepArrays(row_map=row_map, w_eff=w_eff,
+                                      bias=bias))
+        # every prepared array, one entry per step: the program's argument
+        self.arrays: Tuple[_StepArrays, ...] = tuple(arrays)
         self._buffer_set = set(graph.buffer_sources())
         # fused groups (schema v4): ``fused_with`` chains a step into its
         # immediate consumer, which must be the next layer
@@ -673,12 +753,8 @@ class PreparedNetwork:
                                 f"{step.fused_with} is not the next layer")
         if plan.steps and plan.steps[-1].fused_with is not None:
             raise PlanError("last step cannot fuse into a consumer")
-        self._prov: Optional[Dict[str, object]] = None
-
-    def _provenance(self) -> Dict[str, object]:
-        if self._prov is None:
-            self._prov = _plan_provenance(self.plan)
-        return self._prov
+        self._programs = {}
+        self._prov = None
 
     # ------------------------------------------------- batch assembly hooks
     # The serving engine's contract: requests are single samples, the plan
@@ -749,51 +825,57 @@ class PreparedNetwork:
         return apply_block_perm(canon, st.out_perm, block) \
             if len(st.out_perm) > 1 else canon
 
+    def warm(self, *, activation: Optional[Callable] = None,
+             use_pallas: bool = True) -> None:
+        """Compile the batch program ahead of the first request: run it
+        once on a zero batch, without the per-call span and fault site."""
+        x = jnp.zeros(self.input_shape, jnp.float32)
+        jax.block_until_ready(
+            self.program(activation, use_pallas)(self.arrays, x))
+
     def __call__(self, x: jax.Array, *,
                  activation: Optional[Callable[[jax.Array], jax.Array]] = None,
                  use_pallas: bool = True) -> jax.Array:
+        N = self.input_shape[0]
+        if jnp.shape(x)[0] != N:
+            raise PlanError(f"batch {jnp.shape(x)[0]} != planned N={N}")
+        attrs = dict(self._provenance(), batch=int(N),
+                     pallas=bool(use_pallas)) if obs.active() else None
+        return self._dispatch("exec.network", attrs, x, activation,
+                              use_pallas)
+
+    def _forward(self, arrays, x, activation, use_pallas):
         block = self.block
         N, H, W, C = self.input_shape
-        # spans time host dispatch only: nothing here waits for the device
-        active = obs.active()
-        with obs.span("exec.network",
-                      dict(self._provenance(), batch=int(N),
-                           pallas=bool(use_pallas)) if active else None):
-            a = adapt_activation(jnp.asarray(x, jnp.float32), H, W, C)
-            if a.shape[0] != N:
-                raise PlanError(f"batch {a.shape[0]} != planned N={N}")
-            cur = a.reshape(N * H * W, C)
-            if len(self.perms[0]) > 1:
-                cur = apply_block_perm(cur, self.perms[0], block)
-            buffers: Dict[int, jax.Array] = {}
-            last = len(self.steps) - 1
-            for i, st in enumerate(self.steps):
-                faults.site(faults.EXEC_DISPATCH)
-                with obs.span("exec.step", _step_attrs(
-                        self._provenance(), i, self.plan.steps[i])
-                        if active else None):
-                    cur = self._step(st, cur, buffers, block,
-                                     activation if i < last else None,
-                                     use_pallas)
-                if i in self._buffer_set:
-                    buffers[i] = cur
-            out_perm = self.perms[-1]
-            if len(out_perm) > 1:
-                cur = invert_block_perm(cur, out_perm, block)
-            out = cur.reshape(self.steps[-1].out_shape)
-        return out
+        a = adapt_activation(jnp.asarray(x, jnp.float32), H, W, C)
+        cur = a.reshape(N * H * W, C)
+        if len(self.perms[0]) > 1:
+            cur = apply_block_perm(cur, self.perms[0], block)
+        buffers: Dict[int, jax.Array] = {}
+        last = len(self.steps) - 1
+        for i, (st, arr) in enumerate(zip(self.steps, arrays)):
+            with jax.named_scope(step_scope(i, self.plan.steps[i])):
+                cur = self._step(st, arr, cur, buffers, block,
+                                 activation if i < last else None,
+                                 use_pallas)
+            if i in self._buffer_set:
+                buffers[i] = cur
+        out_perm = self.perms[-1]
+        if len(out_perm) > 1:
+            cur = invert_block_perm(cur, out_perm, block)
+        return cur.reshape(self.steps[-1].out_shape)
 
-    def _step(self, st: _NetStep, cur: jax.Array,
+    def _step(self, st: _NetStep, arr: _StepArrays, cur: jax.Array,
               buffers: Dict[int, jax.Array], block: int,
               activation: Optional[Callable[[jax.Array], jax.Array]],
               use_pallas: bool) -> jax.Array:
-        """Dispatch one plan step: gather, pad, kernel, crop, bias, joins."""
-        if st.row_map is None:
+        """Trace one plan step: gather, pad, kernel, crop, bias, joins."""
+        if arr.row_map is None:
             patches = cur
         else:
             padded = jnp.concatenate(
                 [cur, jnp.zeros((1, cur.shape[1]), cur.dtype)])
-            patches = padded[st.row_map].reshape(st.rows_out, st.k_width)
+            patches = padded[arr.row_map].reshape(st.rows_out, st.k_width)
         patches = _pad_axis(_pad_axis(patches, st.block_m, 0),
                             st.block_k, 1)
         fused_res = None
@@ -808,11 +890,11 @@ class PreparedNetwork:
             if fused_res is not None:
                 res_pad = _pad_axis(
                     _pad_axis(fused_res, st.block_m, 0), block, 1)
-            y = ops.rir_matmul(patches, st.w_eff, out_perm,
+            y = ops.rir_matmul(patches, arr.w_eff, out_perm,
                                residual=res_pad, block_m=st.block_m,
                                block_n=block, block_k=st.block_k)
         else:
-            y = jnp.dot(patches, st.w_eff,
+            y = jnp.dot(patches, arr.w_eff,
                         preferred_element_type=jnp.float32)
             if out_perm is not None:
                 y = apply_block_perm(y, out_perm, block)
@@ -820,8 +902,8 @@ class PreparedNetwork:
                 y = y + _pad_axis(
                     _pad_axis(fused_res, st.block_m, 0), block, 1)
         y = y[:st.rows_out, :st.wl.M]
-        if st.bias is not None:
-            y = y + st.bias[None, :]
+        if arr.bias is not None:
+            y = y + arr.bias[None, :]
         for je in st.joins:
             if je.fused:
                 continue

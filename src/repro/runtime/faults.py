@@ -32,8 +32,8 @@ site              fires inside
 ``plan.replan``   the tier-1 full re-plan inside ``resolve_plan`` /
                   ``upgrade_plan``, before the planner runs — "the
                   planner fleet is down" for the degradation ladder
-``exec.dispatch`` the plan executors, once per plan step, immediately
-                  before the kernel dispatch (``PreparedNetwork.__call__``
+``exec.dispatch`` the plan executors, once per executor call, immediately
+                  before the program's dispatch (``PreparedNetwork.__call__``
                   and ``PreparedPlan.__call__``)
 ``ckpt.write``    ``checkpoint.save_pytree`` — between the fully-written
                   temp directory (COMMIT included) and the atomic rename
@@ -137,8 +137,8 @@ _SITE_DOCS: Dict[str, str] = {
         "``upgrade_plan``, before the planner runs — \"the\n"
         "planner fleet is down\" for the degradation ladder",
     EXEC_DISPATCH:
-        "the plan executors, once per plan step, immediately\n"
-        "before the kernel dispatch (``PreparedNetwork.__call__``\n"
+        "the plan executors, once per executor call, immediately\n"
+        "before the program's dispatch (``PreparedNetwork.__call__``\n"
         "and ``PreparedPlan.__call__``)",
     CKPT_WRITE:
         "``checkpoint.save_pytree`` — between the fully-written\n"

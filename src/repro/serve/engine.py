@@ -152,9 +152,13 @@ class _NetworkBackend:
             return [np.asarray(o) for o in outs]
 
     def upgraded(self, resolved):
-        """Build the tier-1 prepared network for an upgraded plan."""
+        """Build the tier-1 prepared network for an upgraded plan and
+        compile its batch program here, off the serving path, so no
+        request batch pays for the compile once it is swapped in."""
         from repro.plan import prepare_network
-        return prepare_network(resolved.plan, self.graph, self.weights)
+        prepared = prepare_network(resolved.plan, self.graph, self.weights)
+        prepared.warm(use_pallas=self.config.use_pallas)
+        return prepared
 
 
 class _LMBackend:
@@ -514,8 +518,8 @@ class ServeEngine:
         Runs only while the engine serves a degraded plan.  Each round
         waits ``upgrade_interval_s``, retries the full planner via
         ``upgrade_plan`` (cache hit counts — another worker may win the
-        race), builds the new prepared network *off* the serving path, and
-        swaps it in atomically between batches.
+        race), builds the new prepared network and compiles its program *off*
+        the serving path, and swaps it in atomically between batches.
         """
         from repro.plan import upgrade_plan
 

@@ -11,6 +11,7 @@ import dataclasses
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.dataflow import ConvWorkload
@@ -20,7 +21,7 @@ from repro.core.workloads import (init_graph_weights, input_channels,
                                   is_depthwise, weight_shape)
 from repro.kernels import ops, ref
 from repro.plan import (JoinSpec, NetworkPlanner, PlanError, PlannerOptions,
-                        adapt_activation, execute_network,
+                        PreparedNetwork, adapt_activation, execute_network,
                         execute_network_reference, from_layers,
                         layout_block_perm, mobilenet_v3_graph,
                         prepare_network, resnet50_graph)
@@ -93,8 +94,9 @@ def test_lane_aligned_k_blocks_match_ref_oracle():
         for s in plan.steps))
     ws = init_graph_weights(list(graph.layers), seed=3)
     prepared = prepare_network(plan, graph, ws)
-    assert [(st.k_width, st.block_k, st.w_eff.shape[0])
-            for st in prepared.steps] == [(128, 128, 128), (256, 128, 256)]
+    assert [(st.k_width, st.block_k, arr.w_eff.shape[0])
+            for st, arr in zip(prepared.steps, prepared.arrays)] == \
+        [(128, 128, 128), (256, 128, 256)]
     y, y_ref, _ = run_both(graph, plan=plan, seed=3)
     np.testing.assert_allclose(y, y_ref, rtol=1e-4, atol=1e-3)
 
@@ -299,3 +301,71 @@ def test_plan_graph_mismatch_rejected():
     ws = init_graph_weights(list(other.layers), seed=0)
     with pytest.raises(PlanError):
         prepare_network(plan, other, ws)
+
+
+# -------------------------------------------------------------- one program
+def program_graph():
+    """A 3x3 conv (a row-map gather) into two 1x1s with a skip join, at
+    batch 4: every kind of prepared array the program reads."""
+    return from_layers([
+        ConvWorkload(M=128, C=16, P=16, Q=16, R=3, S=3, name="c3"),
+        ConvWorkload(M=128, C=128, P=16, Q=16, R=1, S=1, name="c1"),
+        ConvWorkload(M=128, C=128, P=16, Q=16, R=1, S=1, name="c1b"),
+    ], "prog", skip_edges=((0, 2),)).with_batch(4)
+
+
+def test_network_traces_once_per_batch_shape(monkeypatch):
+    """Repeated calls, and ``execute_requests`` at every batch size
+    1..max_batch (all padded to the plan's N), trace the network once;
+    another activation is another program."""
+    traces = []
+    real = PreparedNetwork._forward
+    monkeypatch.setattr(PreparedNetwork, "_forward",
+                        lambda self, *a: traces.append(1) or real(self, *a))
+    graph = program_graph()
+    plan = make_plan(graph)
+    ws = init_graph_weights(list(graph.layers), seed=11)
+    prepared = prepare_network(plan, graph, ws)
+    rng = np.random.default_rng(12)
+    x = jnp.asarray(rng.normal(size=graph.input_shape()), jnp.float32)
+    full = [np.asarray(prepared(x)) for _ in range(3)]
+    samples = [x[i] for i in range(prepared.max_batch)]
+    for k in range(1, prepared.max_batch + 1):
+        outs = prepared.execute_requests(samples[:k])
+        for i, o in enumerate(outs):
+            np.testing.assert_array_equal(np.asarray(o), full[0][i])
+    assert len(traces) == 1
+    prepared(x, activation=RELU)
+    prepared(x, activation=RELU)
+    assert len(traces) == 2
+    np.testing.assert_array_equal(full[1], full[0])
+
+
+def _constant_sizes(program_text):
+    """Element counts of every ``stablehlo.constant`` in a program."""
+    sizes = []
+    for line in program_text.splitlines():
+        if "stablehlo.constant" not in line:
+            continue
+        dims = line.rsplit("tensor<", 1)[1].split("x")[:-1]
+        sizes.append(int(np.prod([int(d) for d in dims])))
+    return sizes
+
+
+def test_prepared_arrays_are_program_arguments():
+    """Weights, row maps and biases enter the program as parameters: the
+    lowered program holds no constant beyond a few thousand elements."""
+    graph = program_graph()
+    plan = make_plan(graph)
+    ws = init_graph_weights(list(graph.layers), seed=13)
+    biases = [jnp.full((wl.M,), 0.5, jnp.float32) for wl in graph.layers]
+    prepared = prepare_network(plan, graph, ws, biases=biases)
+    leaves = jax.tree.leaves(prepared.arrays)
+    assert max(a.size for a in leaves) > 4096
+    assert prepared.arrays[0].row_map is not None
+    x = jax.ShapeDtypeStruct(graph.input_shape(), jnp.float32)
+    text = prepared.program().lower(prepared.arrays, x).as_text()
+    assert max(_constant_sizes(text), default=0) <= 4096
+    main = next(ln for ln in text.splitlines() if "func.func public @main"
+                in ln)
+    assert main.count("%arg") == len(leaves) + 1

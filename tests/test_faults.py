@@ -24,7 +24,8 @@ from repro.core.layoutloop import EvalConfig
 from repro.core.workloads import init_graph_weights
 from repro.plan import (ExecutionPlan, NetworkPlanner, PlanCache,
                         PlannerOptions, ResolvedPlan, TIER_NAMES, config_key,
-                        execute_network, from_layers, resolve_plan)
+                        execute_network, from_layers, prepare_network,
+                        prepare_plan, resolve_plan)
 from repro.runtime import faults
 from repro.runtime.retry import RetryPolicy, retry_call
 
@@ -427,6 +428,30 @@ def test_exec_dispatch_injection_and_retry_bitidentical(obs_enabled):
             site="exec.dispatch", policy=FAST, sleep=NOSLEEP))
     assert sched.injected("exec.dispatch") == 2
     assert np.array_equal(y0, y1)
+
+
+def test_exec_dispatch_fires_once_per_call():
+    """The site fires once per executor call, however many plan steps the
+    program holds: the network executor and the GEMM chain alike."""
+    graph = tiny_graph(3)
+    plan = tiny_plan(graph)
+    ws = init_graph_weights(list(graph.layers), seed=0)
+    prepared = prepare_network(plan, graph, ws)
+    x = jnp.asarray(np.random.default_rng(2).normal(
+        size=graph.input_shape()), jnp.float32)
+    chain_ws = [jnp.ones((16, 64), jnp.float32),
+                jnp.ones((64, 64), jnp.float32),
+                jnp.ones((64, 64), jnp.float32)]
+    chain = prepare_plan(plan, 16, chain_ws)
+    sched = faults.FaultSchedule(seed=0, sites={
+        "exec.dispatch": faults.SiteSpec(count=0)})
+    with faults.injecting(sched):
+        for _ in range(2):
+            prepared(x)
+        assert sched.visits("exec.dispatch") == 2
+        chain(jnp.ones((64, 16), jnp.float32))
+    assert sched.visits("exec.dispatch") == 3
+    assert sched.injected("exec.dispatch") == 0
 
 
 def test_armed_unrelated_sites_leave_plan_json_identical(tmp_path):

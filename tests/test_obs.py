@@ -15,7 +15,8 @@ from repro.core.layoutloop import EvalConfig
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.plan import (NetworkPlanner, PlannerOptions, execute_network,
-                        from_layers)
+                        from_layers, prepare_network)
+from repro.plan.executor import step_scope, step_scopes
 
 SMALL_LAYOUTS = tuple(Layout.parse(s) for s in ("HWC_C32", "HWC_H32"))
 
@@ -270,6 +271,13 @@ def test_measure_blocks_and_returns_result(obs_reset):
 
 
 # -------------------------------------------------- executor instrumentation
+def _step_scopes(prepared, x):
+    """The plan-step indices the lowered program's scopes name, in order."""
+    text = prepared.program().lower(prepared.arrays, x).as_text(
+        debug_info=True)
+    return step_scopes(text)
+
+
 def test_execute_network_bit_identical_and_traced(obs_reset):
     graph = tiny_graph(2)
     plan = tiny_plan(graph)
@@ -287,22 +295,27 @@ def test_execute_network_bit_identical_and_traced(obs_reset):
         obs.reset()
     assert (y_off == y_on).all(), "tracing changed numeric outputs"
 
-    steps = [e for e in evs if e["name"] == "exec.step"]
-    nets = [e for e in evs if e["name"] == "exec.network"]
-    assert len(steps) == len(plan.steps) and len(nets) == 1
-    for i, e in enumerate(steps):
-        a = e["attrs"]
-        assert a["plan_id"] == plan.plan_id
-        assert a["graph_hash"] == plan.graph_hash
-        assert a["schema_version"] == plan.version
-        assert a["step"] == i
-        assert a["layer"] == plan.steps[i].layer
-    assert nets[0]["attrs"]["plan_id"] == plan.plan_id
+    # one host span per call; the steps are scopes inside the program
+    assert [e["name"] for e in evs if e["name"].startswith("exec.")] == \
+        ["exec.network"]
+    (net,) = [e for e in evs if e["name"] == "exec.network"]
+    a = net["attrs"]
+    assert a["plan_id"] == plan.plan_id
+    assert a["graph_hash"] == plan.graph_hash
+    assert a["schema_version"] == plan.version
+    assert a["batch"] == graph.input_shape()[0]
+    prepared = prepare_network(plan, graph, ws)
+    text = prepared.program().lower(prepared.arrays, x).as_text(
+        debug_info=True)
+    for i, step in enumerate(plan.steps):
+        assert step_scope(i, step) in text
 
 
 def test_traced_execute_network_never_fences(obs_reset, monkeypatch):
     """Spans time host dispatch: no ``block_until_ready`` anywhere in a
-    traced execution, and one ``exec.step`` per plan step, fused or not."""
+    traced execution, one ``exec.network`` span per call, and one
+    ``exec.step`` scope per plan step in the program, in step order,
+    fused or not."""
     import jax
     graph = tiny_graph(3)
     plan = tiny_plan(graph)
@@ -312,23 +325,23 @@ def test_traced_execute_network_never_fences(obs_reset, monkeypatch):
     ws = init_graph_weights(list(graph.layers), seed=0)
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.normal(size=graph.input_shape()), jnp.float32)
-    y_off = np.asarray(execute_network(plan, graph, x, ws))
+    prepared = prepare_network(plan, graph, ws)
+    y_off = np.asarray(prepared(x))
 
     fences = []
     real = jax.block_until_ready
     monkeypatch.setattr(jax, "block_until_ready",
                         lambda v: fences.append(1) or real(v))
     obs.enable()
-    y_on = execute_network(plan, graph, x, ws)
+    y_on = [prepared(x) for _ in range(2)]
     monkeypatch.undo()
     assert fences == []
-    steps = [e for e in obs.events() if e["name"] == "exec.step"]
-    assert [e["attrs"]["step"] for e in steps] == list(range(len(plan.steps)))
-    assert [e["attrs"]["layer"] for e in steps] == \
-        [s.layer for s in plan.steps]
-    assert (np.asarray(y_on) == y_off).all()
-
-
+    nets = [e for e in obs.events() if e["name"] == "exec.network"]
+    assert len(nets) == 2
+    assert not [e for e in obs.events() if e["name"] == "exec.step"]
+    assert _step_scopes(prepared, x) == list(range(len(plan.steps)))
+    for y in y_on:
+        assert (np.asarray(y) == y_off).all()
 # ------------------------------------------------------------------ planner
 def test_planner_spans_and_gauges(obs_enabled):
     tiny_plan(tiny_graph(2))

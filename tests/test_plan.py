@@ -14,7 +14,8 @@ from repro.plan import (ExecutionPlan, NetworkPlanner, PlanCache, PlanError,
                         layout_block_perm, mobilenet_v3_graph, prepare_plan,
                         resnet50_graph)
 from repro.plan.executor import (apply_block_perm, invert_block_perm,
-                                 permute_weight_blocks)
+                                 permute_weight_blocks, step_scope,
+                                 step_scopes)
 
 SMALL_LAYOUTS = tuple(Layout.parse(s)
                       for s in ("HWC_C32", "HWC_H32", "HWC_C4W8"))
@@ -270,8 +271,9 @@ def test_prepared_plan_reuse_matches_per_call_setup():
 
 
 def test_traced_prepared_plan_never_fences(obs_enabled, monkeypatch):
-    """A traced GEMM chain waits for the device nowhere and emits one
-    ``exec.step`` per plan step; its output is the untraced one's."""
+    """A traced GEMM chain waits for the device nowhere, emits one
+    ``exec.chain`` span per call and one ``exec.step`` scope per plan step
+    in its program, in step order; its output is the untraced one's."""
     import jax
     opts = PlannerOptions(switch_modes=("rir",), layouts=SMALL_LAYOUTS,
                           parallel_dims=("C", "P", "Q"))
@@ -290,14 +292,20 @@ def test_traced_prepared_plan_never_fences(obs_enabled, monkeypatch):
     real = jax.block_until_ready
     monkeypatch.setattr(jax, "block_until_ready",
                         lambda v: fences.append(1) or real(v))
-    y_on = prepared(x)
+    y_on = [prepared(x) for _ in range(2)]
     monkeypatch.undo()
     assert fences == []
-    steps = [e for e in obs.events() if e["name"] == "exec.step"]
-    assert [e["attrs"]["step"] for e in steps] == list(range(len(plan.steps)))
-    (chain,) = [e for e in obs.events() if e["name"] == "exec.chain"]
-    assert all(chain["ts"] <= e["ts"] for e in steps)
-    np.testing.assert_array_equal(np.asarray(y_on), y_off)
+    chains = [e for e in obs.events() if e["name"] == "exec.chain"]
+    assert len(chains) == 2
+    assert chains[0]["attrs"]["plan_id"] == plan.plan_id
+    assert not [e for e in obs.events() if e["name"] == "exec.step"]
+    text = prepared.program().lower(prepared.arrays, x).as_text(
+        debug_info=True)
+    assert step_scopes(text) == list(range(len(plan.steps)))
+    for i, step in enumerate(plan.steps):
+        assert step_scope(i, step) in text
+    for y in y_on:
+        np.testing.assert_array_equal(np.asarray(y), y_off)
 
 
 def test_stale_prepared_plan_rejected():

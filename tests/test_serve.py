@@ -228,6 +228,41 @@ def test_degraded_engine_upgrades_in_background(cache):
         assert a.shape == b.shape and np.isfinite(a).all()
 
 
+def test_upgraded_plan_is_compiled_before_swap(cache, monkeypatch):
+    """The upgrader compiles the new plan's program before the swap, so the
+    batches served after it trace nothing."""
+    from repro.plan import PreparedNetwork
+    traces = {}
+    real = PreparedNetwork._forward
+
+    def counting(self, *a):
+        traces[id(self)] = traces.get(id(self), 0) + 1
+        return real(self, *a)
+
+    monkeypatch.setattr(PreparedNetwork, "_forward", counting)
+    down = faults.FaultSchedule(seed=0, sites={
+        "plan.replan": faults.SiteSpec(count=3, exc="RuntimeError")})
+    cfg = ServeConfig(graph="tiny", max_batch=2, workers=1,
+                      upgrade_interval_s=0.01, queue_capacity=8,
+                      layouts=("HWC_H32",))   # distinct opts: its own key
+    with faults.injecting(down):
+        eng = ServeEngine(cfg, cache=cache, sleep=_nosleep)
+    assert eng.resolved.degraded
+    with eng:
+        wait = threading.Event()
+        for _ in range(3000):           # no request in flight: poll the swap
+            if eng.resolved.tier <= 1:
+                break
+            wait.wait(0.01)
+        assert eng.resolved.tier == 1, "background upgrade never landed"
+        with eng._swap_lock:
+            upgraded = eng._prepared
+        assert traces.get(id(upgraded)) == 1, "swapped in uncompiled"
+        outs = eng.serve(_samples(eng, 3))
+    assert traces[id(upgraded)] == 1
+    assert all(np.isfinite(o).all() for o in outs)
+
+
 # ------------------------------------------------------ reason + spans
 def test_resolved_plan_reason_records_ladder_descent():
     from repro.obs.smoke import build_graph

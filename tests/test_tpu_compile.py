@@ -1,9 +1,10 @@
 """The main-path kernels compile for a TPU v5e, with no chip attached.
 
 Interpret-mode tests cannot see what the TPU compiler refuses (unaligned
-blocks, unsupported primitives); these compile each kernel for a described
-``v5e:2x2`` topology at real widths and assert the Mosaic custom call is in
-the program.  The topology is described inside a fixture, never at import:
+blocks, unsupported primitives); these compile each kernel, and the whole
+prepared ResNet-50 as the one program the executor runs, for a described
+``v5e:2x2`` topology at real widths and assert the Mosaic custom calls are
+in the program.  The topology is described inside a fixture, never at import:
 only the worker that runs this file loads the TPU library.
 """
 import pathlib
@@ -61,12 +62,18 @@ def _compile(fn, *specs) -> str:
 
 
 @pytest.fixture(scope="module")
-def resnet50_steps():
+def resnet50_prepared():
     graph = resnet50_graph()
     plan = ExecutionPlan.from_json(GOLDEN_RESNET50.read_text())
-    prepared = prepare_network(plan, graph,
-                               init_graph_weights(list(graph.layers)))
-    return {st.wl.name: st for st in prepared.steps}
+    return prepare_network(plan, graph,
+                           init_graph_weights(list(graph.layers)))
+
+
+@pytest.fixture(scope="module")
+def resnet50_steps(resnet50_prepared):
+    """Each layer's static step and its prepared arrays, by layer name."""
+    return {st.wl.name: (st, arr) for st, arr in
+            zip(resnet50_prepared.steps, resnet50_prepared.arrays)}
 
 
 @pytest.mark.parametrize("layer,k", [
@@ -76,18 +83,36 @@ def resnet50_steps():
 ])
 def test_rir_matmul_compiles_at_executor_blocks(one_chip, compiled_kernels,
                                                 resnet50_steps, layer, k):
-    st = resnet50_steps[layer]
+    st, arr = resnet50_steps[layer]
     assert st.k_width == k
-    assert st.block_k % 128 == 0 or st.block_k == st.w_eff.shape[0]
+    assert st.block_k % 128 == 0 or st.block_k == arr.w_eff.shape[0]
     rows = -(-st.rows_out // st.block_m) * st.block_m
-    a = jax.ShapeDtypeStruct((rows, st.w_eff.shape[0]), jnp.float32,
+    a = jax.ShapeDtypeStruct((rows, arr.w_eff.shape[0]), jnp.float32,
                              sharding=one_chip)
-    b = jax.ShapeDtypeStruct(st.w_eff.shape, jnp.float32, sharding=one_chip)
+    b = jax.ShapeDtypeStruct(arr.w_eff.shape, jnp.float32, sharding=one_chip)
     perm = st.out_perm if len(st.out_perm) > 1 else None
     hlo = _compile(lambda a, b: ops.rir_matmul(
         a, b, perm, block_m=st.block_m, block_n=128, block_k=st.block_k),
         a, b)
     assert "tpu_custom_call" in hlo
+
+
+def test_prepared_resnet50_compiles_as_one_program(one_chip,
+                                                  compiled_kernels,
+                                                  resnet50_prepared):
+    """The whole prepared network is one program for the chip, with one
+    Mosaic kernel per plan step inside it."""
+    prepared = resnet50_prepared
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    arrays = jax.tree.map(spec, prepared.arrays)
+    x = jax.ShapeDtypeStruct(prepared.input_shape, jnp.float32,
+                             sharding=one_chip)
+    hlo = prepared.program().lower(arrays, x).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == \
+        len(prepared.plan.steps)
 
 
 def test_gqa_decode_compiles_at_llama3p2_3b_widths(one_chip,

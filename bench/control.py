@@ -7,11 +7,13 @@
 Each seed is one whole run of the cell as ``run.py`` makes it (set-up from
 the seed, the cell's own mix for ``--seconds``, the seeded sample of
 served requests, the check).  For a control seed the control takes the
-program's place before the check: the reference at bf16_3x on the same
-sampled requests, judged by the same ``run.judge`` as every run.  One JSON
-line per seed gives ``correct`` and the numbers compared; the last line
-gives the lower reading (the largest the program gives), the upper (the
-smallest the control gives), and whether every control run read
+program's place before the check: the system's reference one precision
+step below the configuration's (``control_precision``; bf16_3x for the
+conv graphs' ``float32, highest``) on the same sampled requests, judged
+by the same ``run.judge`` and the system's ``gap`` as every run.  One
+JSON line per seed gives ``correct`` and the numbers compared; the last
+line gives the lower reading (the largest the program gives), the upper
+(the smallest the control gives), and whether every control run read
 ``correct`` false.  The benchmark's own runs never run this.
 """
 from __future__ import annotations
@@ -35,6 +37,12 @@ def main(argv=None) -> int:
     ap.add_argument("--seeds", type=_ints, default=[])
     ap.add_argument("--control-seeds", type=_ints, default=[])
     args = ap.parse_args(argv)
+    try:
+        cell = run.load_cell(run.HERE.parent, run.HERE, args.workload)
+        name, _ = run.check_limit(cell.config)
+    except run.SetupError as e:
+        print(f"control: {e}", file=sys.stderr)
+        return 2
     readings = {"program": [], "control": []}
     verdicts = {"program": [], "control": []}
     for seed, control in ([(s, False) for s in args.seeds]
@@ -47,7 +55,7 @@ def main(argv=None) -> int:
             print(f"control: {e}", file=sys.stderr)
             return 2
         side = "control" if control else "program"
-        readings[side].append(out["check"]["max_rel_err"]["value"])
+        readings[side].append(out["check"][name]["value"])
         verdicts[side].append(out["correct"])
         print(json.dumps({"seed": seed, "side": side,
                           "correct": out["correct"], "check": out["check"]}),

@@ -4,27 +4,57 @@
     python3 bench/run.py --workload resnet50.offline --seed 7 --seconds 10 --trace 0
 
 A run reads the cell's configuration (``bench/configs/<config>.json``) and
-traffic mix (``bench/traffic/<traffic>.json``), and then:
+traffic mix (``bench/traffic/<traffic>.json``).  The configuration names
+the system that serves it (``"system": "<name>"``, loaded by path from
+``bench/systems/<name>.py`` once the program's ``src/`` is importable;
+without the key, ``conv_graph``).  This is the one statement of what a
+system module provides; the harness knows nothing else of what it serves:
 
-1. set-up: weights and a pool of request inputs from ``--seed`` (made on
-   the device by ``reference.py``'s generators), a
-   ``repro.api.ServeEngine`` over the configuration's layer graph with
-   its plan cached in ``bench/.cache/plans``, and a warm-up that serves
-   every batch size from 1 to ``max_batch`` through the engine.  A plan
-   the degradation ladder could not produce in full fails the run.
+* ``start(config, mix, seed, chips, plan_dir)``: the engine (not yet
+  started), the request pool, an opaque ``weights`` object the reference
+  can use once the engine is freed, and a dict of facts for the run's
+  stdout.  The engine has ``submit(payload)`` (a ticket whose
+  ``result(timeout)`` is the output), ``queue_depth()``, and is a context
+  manager that starts and stops it; while ``repro.obs`` is on it records
+  each batch's size in the ``serve.batch_size`` histogram.
+* ``reference(config, inputs, outputs, weights, precision)``: the plain
+  reference's result for each sampled request, from its pool entry and
+  the output the program served for it (a reference that depends on the
+  input alone ignores the outputs; a served LM's runs over the prompt
+  and its served tokens).  ``precision`` is ``"highest"`` for the check.
+* ``gap(config, out, ref)``: the number compared for one request, between
+  an output and the reference's result for it.  The check takes the
+  largest over the sample and holds it to the configuration's one
+  ``check`` entry, whose key names it (``max_rel_err`` for the conv
+  graphs).
+* ``control_precision(config)``: the precision one step below the
+  configuration's.  The reference's results there are the control's
+  outputs, put in the program's place.
+* ``work(config)``: a ``work.Work`` for one request.
+
+A system raises ``work.SetupError`` where the run cannot be made (the
+harness's ``SetupError`` is the same class).
+
+Then:
+
+1. set-up: ``start`` from ``--seed`` (plans or other set-up caches go in
+   ``bench/.cache/plans``), and a warm-up that serves every batch size from
+   1 to ``serve.max_batch`` through the engine.
 2. the window: ``--seconds`` of the mix (``loadgen.py``), requests entering
    only through ``engine.submit``.  Compilations inside it are counted.
 3. the check (``judge``): a seeded sample of the requests served in the
-   window is compared with ``reference.py`` at highest precision, once
-   the engine's device state is freed.  ``control.py`` drives the same
+   window is compared by the system's ``gap`` with its reference at
+   highest precision, once the engine's device state is freed.  ``control.py`` drives the same
    run with the control's outputs in the program's place.
 
 ``--trace 0`` prints the cell's end-to-end metrics.  ``--trace 1`` runs the
 same window traced: its first half under the JAX profiler alone (device
 numbers, from ``trace_reduce.py``), its second half with the program's own
-spans and histograms on as well (``repro.obs``, which fences every
-executor step), and prints the per-layer metrics, each read by its own
-reader ``bench/metrics/<name>.py``.
+spans, counters and histograms on as well (``repro.obs``), and prints the
+per-layer metrics, each read by its own reader ``bench/metrics/<name>.py``
+from a ``Traced``.  Readers of a share of the chip's peak count the cell's
+``chips``: they take the work as split evenly over the chips, and the
+trace's busy time as the mean over devices.
 
 The last line of stdout is one JSON object (``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device``, with ``--trace 1`` ``breakdown``, and
@@ -49,7 +79,8 @@ import pathlib  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import threading  # noqa: E402
-from typing import Dict, List, Optional  # noqa: E402
+import types  # noqa: E402
+from typing import Dict, List, Optional, Tuple  # noqa: E402
 
 HERE = pathlib.Path(__file__).resolve().parent
 if str(HERE) not in sys.path:
@@ -57,14 +88,11 @@ if str(HERE) not in sys.path:
 
 import loadgen  # noqa: E402
 import work as work_mod  # noqa: E402
+from work import SetupError  # noqa: E402
 
 # the reference's sample: this many requests served in the window, at most
 CHECK_SAMPLE = 64
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-
-
-class SetupError(Exception):
-    """The run cannot be made here; no result is printed."""
 
 
 def log(msg: str) -> None:
@@ -85,6 +113,7 @@ class Cell:
     mix: Dict
     end_to_end: List[Dict]
     per_layer: List[Dict]
+    system: Optional[types.ModuleType] = None   # set by ``open_cell``
 
 
 def _reports(metric: Dict, cell: str) -> bool:
@@ -109,6 +138,24 @@ def load_cell(root: pathlib.Path, bench: pathlib.Path, workload: str) -> Cell:
     return Cell(workload, int(w["chips"]), config, mix, e2e, layer)
 
 
+def _load(path: pathlib.Path, prefix: str, name: str) -> types.ModuleType:
+    spec = importlib.util.spec_from_file_location(
+        prefix + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_system(bench: pathlib.Path, name: str) -> types.ModuleType:
+    """The system module a configuration names: ``systems/<name>.py``.
+    Its top-level imports run here, so the program's ``src/`` has to be on
+    ``sys.path`` first (``open_cell``)."""
+    path = bench / "systems" / f"{name}.py"
+    if not path.is_file():
+        raise SetupError(f"no system {name!r}: {path} does not exist")
+    return _load(path, "bench_system_", name)
+
+
 def load_reader(bench: pathlib.Path, name: str):
     """The reader of a per-layer metric: ``metrics/<name>.py`` where a mix
     needs its own computation, else the one reader of its base name
@@ -116,18 +163,15 @@ def load_reader(bench: pathlib.Path, name: str):
     path = bench / "metrics" / f"{name}.py"
     if not path.is_file():
         path = bench / "metrics" / f"{name.split('.')[0]}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(path, "bench_metric_", name).read
 
 
 # ------------------------------------------------------------------ the chip
 def enable_caches(bench: pathlib.Path) -> None:
     """JAX's persistent compilation cache at a fixed path in the checkout,
-    keeping every program, however fast it compiled: the eager executor
-    runs hundreds of sub-second ones."""
+    keeping every program, however fast it compiled: besides each batch
+    size's program, the serving path runs small sub-second ones (slices,
+    pads, concatenations) that would otherwise compile in every run."""
     import jax
     jax.config.update("jax_compilation_cache_dir",
                       str(bench / ".cache" / "jax"))
@@ -149,6 +193,13 @@ def find_device(chips: int, require_tpu: bool) -> Dict:
         work_mod.peak_for(dev.device_kind)   # an unknown kind is an error
     return {"platform": dev.platform, "kind": dev.device_kind,
             "count": len(devices)}
+
+
+def chip_peak(device: Dict) -> Optional[Dict]:
+    """One chip's published peaks; none off the TPU."""
+    if device["platform"] != "tpu":
+        return None
+    return work_mod.peak_for(device["kind"])
 
 
 class CompileCounter:
@@ -177,29 +228,6 @@ def memory_peak_bytes(n: int) -> int:
 
 
 # ---------------------------------------------------------------- the system
-def build_engine(config: Dict, weights, plan_dir: pathlib.Path):
-    from repro.api import PlanCache, ServeConfig, ServeEngine, from_layers
-    from repro.core.dataflow import ConvWorkload
-
-    layers = [ConvWorkload(N=1, M=l["M"], C=1 if l.get("depthwise") else l["C"],
-                           P=l["P"], Q=l["Q"], R=l["R"], S=l["S"],
-                           stride=l["stride"], name=l["name"])
-              for l in config["layers"]]
-    graph = from_layers(layers, name=config["name"],
-                        skip_edges=[tuple(e) for e in config["skip_edges"]])
-    serve = config["serve"]
-    sc = ServeConfig(graph=serve["graph"], max_batch=int(serve["max_batch"]),
-                     queue_capacity=int(serve["queue_capacity"]),
-                     plan_deadline=900.0)
-    eng = ServeEngine(sc, cache=PlanCache(plan_dir), graph=graph,
-                      weights=weights)
-    if eng.resolved.degraded:
-        raise SetupError(f"plan resolved at tier {eng.resolved.tier_name} "
-                         f"({eng.resolved.reason}); a degraded plan is a "
-                         f"different system")
-    return eng
-
-
 def warm_up(eng, payloads, max_batch: int, tries: int = 4) -> None:
     """Serve every batch size 1..max_batch once.  A one-request blocker
     keeps the worker busy while the k requests queue, so they are
@@ -301,21 +329,25 @@ class GcPauses:
 
 
 # ---------------------------------------------------------------- the check
-def reference_by_sample(config: Dict, inputs, weights, kept,
-                        precision: str = "highest") -> Dict:
-    """The reference's output for the input of each sampled request."""
-    import numpy as np
+def check_limit(config: Dict) -> Tuple[str, float]:
+    """The name of the number the check compares, and its limit: the
+    configuration's one ``check`` entry."""
+    if len(config.get("check", {})) != 1:
+        raise SetupError("a configuration's \"check\" holds exactly one "
+                         "entry: the number compared and its limit")
+    (name, limit), = config["check"].items()
+    return name, float(limit)
 
-    import reference
 
-    samples = sorted({req.sample for req, _ in kept})
-    if not samples:
-        return {}
-    outs = reference.reference_outputs(
-        config["layers"], config["skip_edges"],
-        np.stack([inputs[s] for s in samples]), weights,
-        int(config["serve"]["max_batch"]), precision=precision)
-    return dict(zip(samples, outs))
+def reference_results(cell: Cell, served: Served,
+                      precision: str = "highest") -> list:
+    """The system's reference result for each sampled request, in the
+    order of ``served.kept``, from its input and its served output."""
+    if not served.kept:
+        return []
+    return list(cell.system.reference(
+        cell.config, [served.pool[req.sample] for req, _ in served.kept],
+        [out for _, out in served.kept], served.weights, precision))
 
 
 # ------------------------------------------------------------------- a run
@@ -323,26 +355,30 @@ def reference_by_sample(config: Dict, inputs, weights, kept,
 class Traced:
     """What a traced window leaves for the per-layer readers."""
 
-    hist: Dict[str, list]
+    hist: Dict[str, list]    # every histogram of the second half, by key
+    counters: Dict[str, float]   # every counter of the second half, by key
     spans: List[Dict]
     trace: object            # trace_reduce.TraceSummary or None
     window_s: float          # host-clock length of the profiled half
     served: int              # requests completed in it
-    work: work_mod.Work
+    work: work_mod.Work      # of one request
     max_batch: int
-    peak: Optional[Dict]
+    peak: Optional[Dict]     # of one chip
+    chips: int               # the cell's
 
 
 def open_cell(root: pathlib.Path, bench: pathlib.Path, workload: str, *,
               require_tpu: bool = True):
-    """The cell's spec and the device it runs on; the program importable
-    and JAX's caches set.  Raises ``SetupError`` before any work."""
+    """The cell's spec with its system loaded, and the device it runs on;
+    the program importable from this checkout's ``src/`` and JAX's caches
+    set.  Raises ``SetupError`` before any work."""
     cell = load_cell(root, bench, workload)
     if not (root / "src" / "repro").is_dir():
         raise SetupError(f"no program under {root / 'src'}: run from a "
                          f"checkout of the repository")
     if str(root / "src") not in sys.path:
         sys.path.insert(0, str(root / "src"))
+    cell.system = load_system(bench, cell.config.get("system", "conv_graph"))
     enable_caches(bench)
     return cell, find_device(cell.chips, require_tpu)
 
@@ -353,34 +389,27 @@ class Served:
 
     win: loadgen.WindowResult
     kept: list               # (request, output) pairs sampled from the seed
-    weights: list
-    inputs: object
+    weights: object          # the system's, for its reference
+    pool: list               # the request inputs
     setup_s: float
     traced: Optional[Traced]
 
 
 def start_engine(cell: Cell, bench: pathlib.Path, seed: int,
                  t_start: float):
-    """Weights and the request pool from ``seed``, and the engine over
-    them (not yet started)."""
-    import reference
-
-    layers = cell.config["layers"]
-    weights = reference.init_weights(layers, seed)
-    inputs = reference.make_inputs(layers, seed, int(cell.mix["pool"]),
-                                   image=cell.config.get("input"))
-    eng = build_engine(cell.config, weights, bench / ".cache" / "plans")
-    info(plan_tier=eng.resolved.tier_name, plan_id=eng.resolved.plan.plan_id,
-         plan_s=time.perf_counter() - t_start)
-    return eng, weights, inputs
+    """The system's engine (not yet started), request pool and weights,
+    from ``seed``."""
+    eng, pool, weights, facts = cell.system.start(
+        cell.config, cell.mix, seed, cell.chips, bench / ".cache" / "plans")
+    info(**facts, start_s=time.perf_counter() - t_start)
+    return eng, pool, weights
 
 
 def serve_cell(cell: Cell, bench: pathlib.Path, device: Dict, seed: int,
                seconds: float, trace: bool, counter: CompileCounter, *,
                t_start: float) -> Served:
     """Set up the engine from ``seed``, warm it, and serve one window."""
-    eng, weights, inputs = start_engine(cell, bench, seed, t_start)
-    payloads = list(inputs)
+    eng, payloads, weights = start_engine(cell, bench, seed, t_start)
     max_batch = int(cell.config["serve"]["max_batch"])
     keep = loadgen.Reservoir(CHECK_SAMPLE, seed)
     traced = None
@@ -407,30 +436,29 @@ def serve_cell(cell: Cell, bench: pathlib.Path, device: Dict, seed: int,
     # the engine's device state goes before the reference runs
     del eng
     gc.collect()
-    return Served(win, keep.items, weights, inputs, setup_s, traced)
+    return Served(win, keep.items, weights, payloads, setup_s, traced)
 
 
-def control_outputs(config: Dict, served: Served) -> list:
+def control_outputs(cell: Cell, served: Served) -> list:
     """The control in the program's place: for the same sampled requests,
-    the reference computed one step below the configuration's precision
-    (bf16_3x for ``float32, highest``)."""
-    low = reference_by_sample(config, served.inputs, served.weights,
-                              served.kept, precision="bf16_3x")
-    return [(req, low[req.sample]) for req, _ in served.kept]
+    the reference's results one step below the configuration's precision
+    (the system's ``control_precision``)."""
+    low = reference_results(cell, served,
+                            cell.system.control_precision(cell.config))
+    return [(req, out) for (req, _), out in zip(served.kept, low)]
 
 
-def judge(config: Dict, served: Served, kept, unanswered: int):
+def judge(cell: Cell, served: Served, kept, unanswered: int):
     """``correct``, and each number compared beside its limit: the largest
-    relative gap between a sampled output and the reference at highest
-    precision, and the due requests left unanswered."""
-    import reference
-
-    ref = reference_by_sample(config, served.inputs, served.weights, kept)
-    err = max((reference.rel_err(out, ref[req.sample]) for req, out in kept),
-              default=float("inf"))
-    limit = float(config["check"]["max_rel_err"])
+    of the system's ``gap`` between a sampled output (``kept``, in the
+    order of ``served.kept``) and the reference at highest precision over
+    the served request, and the due requests left unanswered."""
+    name, limit = check_limit(cell.config)
+    ref = reference_results(cell, served)
+    err = max((float(cell.system.gap(cell.config, out, r))
+               for (_, out), r in zip(kept, ref)), default=float("inf"))
     correct = bool(kept) and unanswered == 0 and err <= limit
-    return correct, {"max_rel_err": {"value": err, "limit": limit},
+    return correct, {name: {"value": err, "limit": limit},
                      "unanswered": {"value": unanswered, "limit": 0}}
 
 
@@ -452,9 +480,11 @@ def run_cell(root: pathlib.Path, bench: pathlib.Path, workload: str,
     if counter.count:
         log(f"WARNING: {counter.count} compilations inside the window")
 
-    kept = control_outputs(cell.config, served) if control else served.kept
-    correct, compared = judge(cell.config, served, kept,
+    t_check = time.perf_counter()
+    kept = control_outputs(cell, served) if control else served.kept
+    correct, compared = judge(cell, served, kept,
                               facts["due"] - facts["answered"])
+    info(check_s=time.perf_counter() - t_check)
     if trace:
         metrics = {}
         for m in cell.per_layer:
@@ -500,8 +530,8 @@ def _traced_window(eng, payloads, cell, max_batch, seconds, keep, counter,
         time.sleep(max(0.0, t0 + seconds - time.perf_counter()))
         obs.disable()
 
-    # no Python tracer: it would time every Python call of the eager
-    # executor, which is the host path the window measures
+    # no Python tracer: it would time every Python call of the serving
+    # path's host code, which the window measures
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     jax.profiler.start_trace(str(trace_dir), profiler_options=options)
@@ -513,21 +543,19 @@ def _traced_window(eng, payloads, cell, max_batch, seconds, keep, counter,
     finally:
         jax.profiler.stop_trace()
     spans = [e for e in obs.events() if e.get("ev") == "span"]
-    hist = {name: list(obs.hist_samples(name))
-            for name in ("serve.time_in_queue_ms", "serve.batch_size")}
+    counters, _, hists = obs.registry()
+    counters = dict(counters)
+    hist = {name: list(samples) for name, samples in hists.items()}
     obs.reset()
     xplane = trace_reduce.find_xplane(trace_dir)
     summary = trace_reduce.reduce_file(xplane) if xplane else None
     shutil.rmtree(trace_dir, ignore_errors=True)
-    peak = None
-    if device["platform"] == "tpu":
-        peak = work_mod.peak_for(device["kind"])
     return win, Traced(
-        hist=hist, spans=spans, trace=summary,
+        hist=hist, counters=counters, spans=spans, trace=summary,
         window_s=half["b"] - half["a"],
         served=win.completed_between(half["a"], half["b"]),
-        work=work_mod.network_work(cell.config["layers"]),
-        max_batch=max_batch, peak=peak)
+        work=cell.system.work(cell.config),
+        max_batch=max_batch, peak=chip_peak(device), chips=cell.chips)
 
 
 def print_result(out: Dict) -> None:

@@ -5,10 +5,10 @@ sustains without a growing backlog, in one process on the chip.
     python3 bench/sweep.py --workload <cell> --seed 3 --seconds 16
 
 The cell's mix is an open-loop one (``"arrivals": "gamma"``); its own
-``rate`` is not used.  One engine is set up and warmed as ``run.py``
-does.  A saturating window first measures capacity; then open-loop
-windows of the cell's own mix
-(its arrival process and CV) are offered at fractions of it.  The backlog
+``rate`` is not used.  One engine is set up by the configuration's system
+module and warmed as ``run.py`` does.  A saturating window first measures
+capacity; then open-loop windows of the cell's own mix (its arrival
+process and CV) are offered at fractions of it.  The backlog
 at a moment is the requests due by then less those answered by then; its
 mean over each quarter of the window (read every 10 ms, which evens out
 the phase of the batch in flight) is recorded.  A rate is sustained when
@@ -55,8 +55,7 @@ def main(argv=None) -> int:
     except run.SetupError as e:
         print(f"sweep: {e}", file=sys.stderr)
         return 2
-    eng, _, inputs = run.start_engine(cell, run.HERE, args.seed, t0)
-    payloads = list(inputs)
+    eng, payloads, _ = run.start_engine(cell, run.HERE, args.seed, t0)
     max_batch = int(cell.config["serve"]["max_batch"])
     rows = []
     with eng:

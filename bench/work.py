@@ -23,6 +23,11 @@ from typing import Dict, Sequence
 F32 = 4
 
 
+class SetupError(Exception):
+    """The run cannot be made here; no result is printed.  Raised by the
+    harness and by a system module alike."""
+
+
 def in_channels(layer: Dict) -> int:
     """Channels a layer reads: its own channels when depthwise."""
     return layer["M"] if layer.get("depthwise") else layer["C"]

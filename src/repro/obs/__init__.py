@@ -121,6 +121,10 @@ Spans:
       host blocked on the device after dispatching a batch
   ``serve.fetch``
       device-to-host copies of a batch's request outputs
+  ``lm.prefill``
+      an LM batch's prefill through the wait on its logits
+  ``lm.decode``
+      an LM batch's decode steps (attr steps) through the wait on the last token
   ``train.step``
       one traced training step
 -- end generated --

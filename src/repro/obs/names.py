@@ -90,6 +90,9 @@ SPANS: Dict[str, str] = {
     "serve.batch": "one continuous batch (plan id/tier in attrs)",
     "serve.wait": "host blocked on the device after dispatching a batch",
     "serve.fetch": "device-to-host copies of a batch's request outputs",
+    "lm.prefill": "an LM batch's prefill through the wait on its logits",
+    "lm.decode": "an LM batch's decode steps (attr steps) through the wait "
+                 "on the last token",
     "train.step": "one traced training step",
 }
 

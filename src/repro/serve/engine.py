@@ -28,7 +28,9 @@ histograms, ``serve.requests`` / ``serve.rejected{reason=}`` /
 span carrying ``plan_id`` / ``plan_tier`` / ``plan_reason``.  Inside it, a
 network batch's host phases are spans of their own: ``exec.assemble``,
 ``exec.network`` (dispatch), ``exec.split``, ``serve.wait`` (the host
-blocked on the device) and ``serve.fetch`` (device-to-host copies).
+blocked on the device) and ``serve.fetch`` (device-to-host copies); an LM
+batch's are ``lm.prefill`` (through the wait on its logits), ``lm.decode``
+(the decode steps through the wait on the last token) and ``serve.fetch``.
 """
 from __future__ import annotations
 
@@ -166,11 +168,14 @@ class _LMBackend:
 
     The model runs on the first ``model_axis`` local devices, one (1,
     model_axis) mesh: a one-chip deployment holds one chip even on a host
-    with more.  Parameters are created in place, laid out by the
-    ``distributed.sharding`` rules over that mesh.
+    with more.  Parameters are laid out by the ``distributed.sharding``
+    rules over that mesh: drawn in place by ``LMModel.init`` from the
+    seed, or, given ``weights``, made by ``weights(shardings)`` (the
+    caller's parameters in that layout, the model's tree, shapes and
+    dtypes, or ``ServeError``).
     """
 
-    def __init__(self, config: ServeConfig, cache,
+    def __init__(self, config: ServeConfig, cache, weights,
                  sleep: Callable[[float], None]):
         import jax
 
@@ -203,8 +208,13 @@ class _LMBackend:
         self.model.mesh = self.mesh
         init_key, _ = jax.random.split(jax.random.PRNGKey(config.seed))
         shardings = param_shardings(self.mesh, self.model.param_specs())
-        self.params = jax.jit(self.model.init,
-                              out_shardings=shardings)(init_key)
+        if weights is None:
+            self.params = jax.jit(self.model.init,
+                                  out_shardings=shardings)(init_key)
+        else:
+            self.params = weights(shardings)
+            _check_params(self.params, jax.eval_shape(self.model.init,
+                                                      init_key))
         self.decode = jax.jit(self.model.decode_step)
         self.prefill = jax.jit(self.model.prefill, static_argnums=2)
         self.max_seq = config.prompt_len + config.gen
@@ -221,49 +231,70 @@ class _LMBackend:
                              f"exactly prompt_len tokens")
 
     def run(self, _prepared, payloads: Sequence) -> List[np.ndarray]:
+        """Prefill, ``gen - 1`` greedy decode steps and the fetch of the
+        tokens, each in its own span (``lm.prefill``, ``lm.decode``,
+        ``serve.fetch``); the three add up to the batch."""
         import jax
         import jax.numpy as jnp
 
         B = self.config.max_batch
         k = len(payloads)
-        prompts = np.zeros((B, self.config.prompt_len), np.int32)
-        for i, p in enumerate(payloads):
-            prompts[i] = np.asarray(p, np.int32)
-        prompts = jnp.asarray(prompts)
         gen = self.config.gen
         with self.mesh:
-            t0 = time.perf_counter()
-            if self.cfg.family in ("ssm", "hybrid"):
-                cache = self.model.init_cache(B, self.max_seq)
-                logits = None
-                for t in range(self.config.prompt_len):  # SSM scan-in
-                    cache, logits = self.decode(self.params, cache,
-                                                prompts[:, t])
-            else:
-                cache, logits = self.prefill(self.params, prompts,
-                                             self.max_seq)
-            logits = jax.block_until_ready(logits)
-            t_prefill = time.perf_counter() - t0
+            with obs.span("lm.prefill"):
+                prompts = np.zeros((B, self.config.prompt_len), np.int32)
+                for i, p in enumerate(payloads):
+                    prompts[i] = np.asarray(p, np.int32)
+                prompts = jnp.asarray(prompts)
+                t0 = time.perf_counter()
+                if self.cfg.family in ("ssm", "hybrid"):
+                    cache = self.model.init_cache(B, self.max_seq)
+                    logits = None
+                    for t in range(self.config.prompt_len):  # SSM scan-in
+                        cache, logits = self.decode(self.params, cache,
+                                                    prompts[:, t])
+                else:
+                    cache, logits = self.prefill(self.params, prompts,
+                                                 self.max_seq)
+                logits = jax.block_until_ready(logits)
+                t_prefill = time.perf_counter() - t0
             obs.observe("serve.prefill_ms", t_prefill * 1e3)
-            tokens = jnp.argmax(logits, axis=-1)
-            out = [tokens]
-            t0 = time.perf_counter()
-            for _ in range(gen - 1):
-                cache, logits = self.decode(self.params, cache, tokens)
+            with obs.span("lm.decode", {"steps": gen - 1}):
                 tokens = jnp.argmax(logits, axis=-1)
-                out.append(tokens)
-            tokens = jax.block_until_ready(tokens)
-            t_decode = time.perf_counter() - t0
+                out = [tokens]
+                t0 = time.perf_counter()
+                for _ in range(gen - 1):
+                    cache, logits = self.decode(self.params, cache, tokens)
+                    tokens = jnp.argmax(logits, axis=-1)
+                    out.append(tokens)
+                tokens = jax.block_until_ready(tokens)
+                t_decode = time.perf_counter() - t0
         if gen > 1:
             obs.observe("serve.decode_ms_per_token",
                         t_decode * 1e3 / (gen - 1))
         log.debug("batch of %d: prefill %.1f ms; decode %.1f ms/token",
                   k, t_prefill * 1e3, t_decode * 1e3 / max(1, gen - 1))
-        toks = np.stack([np.asarray(t) for t in out], axis=1)   # (B, gen)
-        return [toks[i] for i in range(k)]
+        with obs.span("serve.fetch"):
+            toks = np.stack([np.asarray(t) for t in out], axis=1)  # (B, gen)
+            return [toks[i] for i in range(k)]
 
     def upgraded(self, resolved):
         return None
+
+
+def _check_params(params, want) -> None:
+    """``params`` has ``want``'s tree, and each leaf its shape and dtype."""
+    import jax
+
+    if jax.tree.structure(params) != jax.tree.structure(want):
+        raise ServeError(f"parameters {jax.tree.structure(params)} are not "
+                         f"the model's {jax.tree.structure(want)}")
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(params),
+                            jax.tree.leaves(want)):
+        if (a.shape, a.dtype) != (b.shape, b.dtype):
+            raise ServeError(
+                f"parameter {jax.tree_util.keystr(path)} is {a.shape} "
+                f"{a.dtype}; the model's is {b.shape} {b.dtype}")
 
 
 def _planner_options(config: ServeConfig):
@@ -293,6 +324,10 @@ class ServeEngine:
 
         with ServeEngine(ServeConfig(graph="tiny", max_batch=4)) as eng:
             outs = eng.serve(samples)
+
+    ``weights`` replaces the seeded draw: a network's per-layer weights,
+    or, for an LM, a function of the parameter shardings that returns the
+    parameters to serve, laid out by them.
     """
 
     def __init__(self, config: ServeConfig, *, cache=None, graph=None,
@@ -305,7 +340,7 @@ class ServeEngine:
         if config.log_level:
             obs.set_level(config.log_level)
         if config.arch is not None:
-            self._backend = _LMBackend(config, self.cache, sleep)
+            self._backend = _LMBackend(config, self.cache, weights, sleep)
         else:
             self._backend = _NetworkBackend(config, self.cache, graph,
                                             weights, sleep)

@@ -126,6 +126,20 @@ def test_gqa_decode_compiles_at_llama3p2_3b_widths(one_chip,
     assert "tpu_custom_call" in hlo
 
 
+def test_gqa_decode_compiles_at_a_nemotron_4_15b_head_shard(one_chip,
+                                                          compiled_kernels):
+    """One chip's share of Nemotron-4-15B over four: 12 query heads in
+    groups of 6 over 2 KV heads of 128, a 640-position cache (prompt 512 +
+    128 generated), batch 8."""
+    B, Hq, Hkv, D, S = 8, 12, 2, 128, 640
+    spec = lambda *s: jax.ShapeDtypeStruct(  # noqa: E731
+        s, jnp.bfloat16, sharding=one_chip)
+    lens = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+    hlo = _compile(ops.gqa_decode, spec(B, Hq, D), spec(B, Hkv, S, D),
+                   spec(B, Hkv, S, D), lens)
+    assert "tpu_custom_call" in hlo
+
+
 def test_linear_scan_compiles_at_rwkv6_1p6b_widths(one_chip,
                                                    compiled_kernels):
     B, H, T, dk = 1, 32, 256, 64              # d_inner 2048 = 32 heads x 64

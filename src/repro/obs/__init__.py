@@ -56,6 +56,8 @@ Counters:
       admissions rejected (reason=capacity|stopped|fault)
   ``serve.batches``
       continuous batches executed
+  ``serve.batches_overlapped``
+      batches launched while an earlier batch of the same worker was in flight
   ``serve.batch_failed{type=}``
       batches whose execution raised
   ``serve.plan_upgrade``
@@ -112,15 +114,15 @@ Spans:
   ``exec.step``
       one plan step's named scope in the program (exec.step:<i>:<layer>), not a host span
   ``exec.split``
-      slice one batch's output into per-request outputs
+      dispatch the per-request split of one batch's output
   ``serve.plan``
       engine plan resolution at startup
   ``serve.batch``
       one continuous batch (plan id/tier in attrs)
   ``serve.wait``
-      host blocked on the device after dispatching a batch
+      host blocked on the device until a launched batch's outputs are ready
   ``serve.fetch``
-      device-to-host copies of a batch's request outputs
+      the rest of a batch's device-to-host copies, begun at its launch
   ``lm.prefill``
       an LM batch's prefill through the wait on its logits
   ``lm.decode``

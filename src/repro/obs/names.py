@@ -46,6 +46,9 @@ COUNTERS: Dict[str, Tuple[Tuple[str, ...], str]] = {
     "serve.rejected": (("reason",), "admissions rejected "
                                     "(reason=capacity|stopped|fault)"),
     "serve.batches": ((), "continuous batches executed"),
+    "serve.batches_overlapped": ((), "batches launched while an earlier "
+                                     "batch of the same worker was in "
+                                     "flight"),
     "serve.batch_failed": (("type",), "batches whose execution raised"),
     "serve.plan_upgrade": ((), "live plan-tier upgrades swapped in"),
     # training
@@ -85,11 +88,13 @@ SPANS: Dict[str, str] = {
     "exec.chain": "host dispatch of one GEMM-chain program",
     "exec.step": "one plan step's named scope in the program "
                  "(exec.step:<i>:<layer>), not a host span",
-    "exec.split": "slice one batch's output into per-request outputs",
+    "exec.split": "dispatch the per-request split of one batch's output",
     "serve.plan": "engine plan resolution at startup",
     "serve.batch": "one continuous batch (plan id/tier in attrs)",
-    "serve.wait": "host blocked on the device after dispatching a batch",
-    "serve.fetch": "device-to-host copies of a batch's request outputs",
+    "serve.wait": "host blocked on the device until a launched batch's "
+                  "outputs are ready",
+    "serve.fetch": "the rest of a batch's device-to-host copies, begun at "
+                   "its launch",
     "lm.prefill": "an LM batch's prefill through the wait on its logits",
     "lm.decode": "an LM batch's decode steps (attr steps) through the wait "
                  "on the last token",

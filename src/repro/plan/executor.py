@@ -49,7 +49,9 @@ shape): the whole per-batch forward is traced once, the prepared arrays
 (effective weights, row maps, biases) reach it as an argument rather than
 as constants of the program, and each plan step's operations sit under a
 ``jax.named_scope`` named ``exec.step:<index>:<layer>``, so the device
-trace can attribute them.  The host dispatches the program once per batch.
+trace can attribute them.  The host dispatches the program once per batch;
+a served batch's per-request outputs come from one more program
+(``split_requests``), so serving a batch returns before the device is done.
 
 All of it validates against the canonical ``execute_network_reference``
 oracle built on ``kernels/ref.py`` conv/depthwise references.
@@ -647,6 +649,16 @@ class _StepArrays(NamedTuple):
     bias: Optional[jax.Array]      # (M,), stored in out_perm block order
 
 
+@jax.jit
+def split_requests(y: jax.Array) -> Tuple[jax.Array, ...]:
+    """Each sample's row of a batch output, sliced in one program.
+
+    Eager ``y[i]`` on a batch output the device is still computing waits
+    for it; dispatching this program does not, so a caller can launch the
+    next batch before this one is done."""
+    return tuple(y[i] for i in range(y.shape[0]))
+
+
 class PreparedNetwork(_Program):
     """``execute_network``'s per-(plan, graph, weights) setup, hoisted.
 
@@ -799,11 +811,14 @@ class PreparedNetwork(_Program):
     def execute_requests(self, samples: Sequence[jax.Array], *,
                          activation: Optional[Callable] = None,
                          use_pallas: bool = True) -> List[jax.Array]:
-        """Run a padded request batch; return each request's own output."""
+        """Run a padded request batch; return each request's own output.
+
+        Returns once the batch program and its split are dispatched: the
+        outputs are device arrays that may still be being computed."""
         y = self(self.assemble_batch(samples), activation=activation,
                  use_pallas=use_pallas)
         with obs.span("exec.split"):
-            return [y[i] for i in range(len(samples))]
+            return list(split_requests(y)[:len(samples)])
 
     # ------------------------------------------------------------- execution
     def _join_term(self, st: _NetStep, je: _JoinExec, buf: jax.Array,
@@ -827,11 +842,12 @@ class PreparedNetwork(_Program):
 
     def warm(self, *, activation: Optional[Callable] = None,
              use_pallas: bool = True) -> None:
-        """Compile the batch program ahead of the first request: run it
-        once on a zero batch, without the per-call span and fault site."""
+        """Compile the batch program and its split ahead of the first
+        request: run both once on a zero batch, without the per-call span
+        and fault site."""
         x = jnp.zeros(self.input_shape, jnp.float32)
-        jax.block_until_ready(
-            self.program(activation, use_pallas)(self.arrays, x))
+        jax.block_until_ready(split_requests(
+            self.program(activation, use_pallas)(self.arrays, x)))
 
     def __call__(self, x: jax.Array, *,
                  activation: Optional[Callable[[jax.Array], jax.Array]] = None,

@@ -15,8 +15,15 @@ the serving loop never blocks on planning.
 Pipeline::
 
     submit() -> [bounded queue] -> assembler (<= plan batch extent)
-             -> PreparedNetwork / LM prefill+decode -> per-request results
+             -> launch: PreparedNetwork dispatch + split, copies to host begun
+                        (LM: prefill + decode, run to completion)
+             -> finish: wait, fetch -> per-request results
                           ^ background tier upgrader (degraded plans only)
+
+Each worker keeps one network batch in flight: it launches batch k+1, if
+one is queued, while the device still runs batch k, and only then finishes
+batch k, so the host's part of a batch overlaps the device's part of the
+one before (``serve.batches_overlapped`` counts such launches).
 
 Backpressure is a *typed* contract: a full queue (or an injected
 ``serve.queue`` admission fault — the chaos schedule's new site) rejects
@@ -24,16 +31,21 @@ with ``QueueFullError`` immediately; admission never blocks and never
 deadlocks.  Observability: ``serve.queue_depth`` gauge,
 ``serve.batch_size`` / ``serve.time_in_queue_ms`` / ``serve.e2e_ms``
 histograms, ``serve.requests`` / ``serve.rejected{reason=}`` /
-``serve.batches`` / ``serve.plan_upgrade`` counters, and a ``serve.batch``
-span carrying ``plan_id`` / ``plan_tier`` / ``plan_reason``.  Inside it, a
-network batch's host phases are spans of their own: ``exec.assemble``,
-``exec.network`` (dispatch), ``exec.split``, ``serve.wait`` (the host
-blocked on the device) and ``serve.fetch`` (device-to-host copies); an LM
-batch's are ``lm.prefill`` (through the wait on its logits), ``lm.decode``
-(the decode steps through the wait on the last token) and ``serve.fetch``.
+``serve.batches`` / ``serve.batches_overlapped`` / ``serve.plan_upgrade``
+counters, and a ``serve.batch`` span carrying ``plan_id`` / ``plan_tier``
+/ ``plan_reason``, from the start of the batch's assembly until its
+tickets are resolved (recorded after the fact: a batch's span interleaves
+with its neighbour's).  Inside it, a network batch's host phases are spans
+of their own: in the launch ``exec.assemble``, ``exec.network``
+(dispatch) and ``exec.split`` (dispatch of the split), in the finish
+``serve.wait`` (the host blocked on the device) and ``serve.fetch`` (the
+rest of the device-to-host copies); an LM batch's are ``lm.prefill``
+(through the wait on its logits), ``lm.decode`` (the decode steps through
+the wait on the last token) and ``serve.fetch``.
 """
 from __future__ import annotations
 
+import dataclasses
 import itertools
 import queue
 import threading
@@ -144,10 +156,19 @@ class _NetworkBackend:
             raise ServeError(f"request shape {a.shape} != planned "
                              f"per-sample shape {self.sample_shape}")
 
-    def run(self, prepared, payloads: Sequence) -> List[np.ndarray]:
-        import jax
+    def launch(self, prepared, payloads: Sequence) -> list:
+        """Assemble and dispatch one batch and its split, and start each
+        output's copy to the host; returns before the device is done."""
         outs = prepared.execute_requests(
             payloads, use_pallas=self.config.use_pallas)
+        for o in outs:
+            o.copy_to_host_async()
+        return outs
+
+    @staticmethod
+    def finish(outs) -> List[np.ndarray]:
+        """Wait for a launched batch and fetch its outputs to the host."""
+        import jax
         with obs.span("serve.wait"):
             outs = jax.block_until_ready(outs)
         with obs.span("serve.fetch"):
@@ -230,10 +251,11 @@ class _LMBackend:
                              f"({self.config.prompt_len},) — requests carry "
                              f"exactly prompt_len tokens")
 
-    def run(self, _prepared, payloads: Sequence) -> List[np.ndarray]:
+    def launch(self, _prepared, payloads: Sequence) -> List[np.ndarray]:
         """Prefill, ``gen - 1`` greedy decode steps and the fetch of the
         tokens, each in its own span (``lm.prefill``, ``lm.decode``,
-        ``serve.fetch``); the three add up to the batch."""
+        ``serve.fetch``); the three add up to the batch.  The decode loop
+        waits on every token, so the batch is done when this returns."""
         import jax
         import jax.numpy as jnp
 
@@ -278,6 +300,10 @@ class _LMBackend:
             toks = np.stack([np.asarray(t) for t in out], axis=1)  # (B, gen)
             return [toks[i] for i in range(k)]
 
+    @staticmethod
+    def finish(outs) -> List[np.ndarray]:
+        return outs
+
     def upgraded(self, resolved):
         return None
 
@@ -312,6 +338,22 @@ def _planner_options(config: ServeConfig):
 # The engine
 # ========================================================================
 _SENTINEL = object()
+
+
+def _on_host(outs) -> bool:
+    """Whether a launch returned its outputs already on the host."""
+    return all(isinstance(o, np.ndarray) for o in outs)
+
+
+@dataclasses.dataclass
+class _Launched:
+    """A batch its backend has launched: its tickets, its ``serve.batch``
+    span's start and attrs, and the backend's handle on its outputs."""
+
+    batch: List[ServeTicket]
+    start_us: float
+    attrs: Optional[dict]
+    outs: object = None
 
 
 class ServeEngine:
@@ -486,65 +528,116 @@ class ServeEngine:
 
     # ------------------------------------------------------------- assembler
     def _worker_loop(self) -> None:
-        limit = self.config.batch_limit
-        while not self._stop.is_set():
-            try:
-                first = self._queue.get(timeout=0.1)
-            except queue.Empty:
-                continue
-            if first is _SENTINEL:
-                return
-            batch = [first]
-            while len(batch) < limit:
-                try:
-                    item = self._queue.get_nowait()
-                except queue.Empty:
-                    break
-                if item is _SENTINEL:
-                    try:
-                        # keep the shutdown token visible to sibling workers
-                        self._queue.put_nowait(_SENTINEL)
-                    except queue.Full:
-                        pass   # workers also exit on the stop event
-                    break
-                batch.append(item)
-            obs.set_gauge("serve.queue_depth", self._queue.qsize())
-            self._run_batch(batch)
+        """Serve batches, launching the next before finishing the last.
 
-    def _run_batch(self, batch: List[ServeTicket]) -> None:
+        At most one launched, unfinished batch is held: while it runs on
+        the device the worker assembles and dispatches the next one, and
+        only then waits on, fetches and resolves it.  The next batch is
+        taken only if it is already queued, so a lone request never waits
+        for a second one.  A batch whose launch returned its outputs on the
+        host (an LM batch: its decode loop waits on every token) has
+        nothing left to wait for and is finished at once; one whose launch
+        returned device arrays is held even if the device is already done,
+        so the next batch reaches the device before this one's tickets are
+        resolved."""
+        in_flight: Optional[_Launched] = None
+        try:
+            while not self._stop.is_set():
+                try:
+                    first = self._queue.get(timeout=0.1) \
+                        if in_flight is None else self._queue.get_nowait()
+                except queue.Empty:
+                    if in_flight is not None:
+                        self._finish(in_flight)
+                        in_flight = None
+                    continue
+                if first is _SENTINEL:
+                    return
+                launched = self._launch(self._gather(first),
+                                        overlapped=in_flight is not None)
+                if in_flight is not None:
+                    self._finish(in_flight)
+                    in_flight = None
+                if launched is not None and _on_host(launched.outs):
+                    self._finish(launched)
+                else:
+                    in_flight = launched
+        finally:
+            if in_flight is not None:
+                self._finish(in_flight)
+
+    def _gather(self, first: ServeTicket) -> List[ServeTicket]:
+        """``first`` and whatever else is queued, up to the batch limit."""
+        batch = [first]
+        while len(batch) < self.config.batch_limit:
+            try:
+                item = self._queue.get_nowait()
+            except queue.Empty:
+                break
+            if item is _SENTINEL:
+                try:
+                    # keep the shutdown token visible to sibling workers
+                    self._queue.put_nowait(_SENTINEL)
+                except queue.Full:
+                    pass   # workers also exit on the stop event
+                break
+            batch.append(item)
+        obs.set_gauge("serve.queue_depth", self._queue.qsize())
+        return batch
+
+    def _launch(self, batch: List[ServeTicket],
+                overlapped: bool) -> Optional[_Launched]:
+        """Start one batch on the prepared network of the moment; ``None``
+        if its launch raised, which fails its tickets alone."""
         with self._swap_lock:
             resolved, prepared = self._resolved, self._prepared
         t_asm = obs.now_us()
-        traced = obs.enabled()
-        if traced:
+        attrs = None
+        if obs.enabled():
             obs.observe("serve.batch_size", len(batch))
             for t in batch:
                 obs.observe("serve.time_in_queue_ms",
                             (t_asm - t.submit_us) / 1e3)
-        attrs = None
-        if obs.active():
             attrs = {"batch": len(batch)}
             if resolved is not None:
                 attrs.update(plan_id=resolved.plan.plan_id,
                              plan_tier=resolved.tier_name,
                              plan_reason=resolved.reason)
+        launched = _Launched(batch, t_asm, attrs)
         try:
-            with obs.span("serve.batch", attrs):
-                outs = self._backend.run(prepared,
-                                         [t.payload for t in batch])
+            launched.outs = self._backend.launch(
+                prepared, [t.payload for t in batch])
         except Exception as e:   # noqa: BLE001 — fail the batch, keep serving
-            obs.inc_counter("serve.batch_failed", type=type(e).__name__)
-            log.warning("batch of %d failed (%s: %s)", len(batch),
-                        type(e).__name__, e)
-            for t in batch:
-                t._resolve(exc=e)
+            self._fail(launched, e)
+            return None
+        if overlapped:
+            obs.inc_counter("serve.batches_overlapped")
+        return launched
+
+    def _finish(self, launched: _Launched) -> None:
+        """Wait for a launched batch, fetch its outputs and resolve its
+        tickets; a failure fails this batch alone."""
+        try:
+            outs = self._backend.finish(launched.outs)
+        except Exception as e:   # noqa: BLE001 — fail the batch, keep serving
+            self._fail(launched, e)
             return
         obs.inc_counter("serve.batches")
         done = obs.now_us()
-        for t, out in zip(batch, outs):
+        traced = obs.enabled()
+        for t, out in zip(launched.batch, outs):
             t._resolve(value=out)
             if traced:
                 obs.observe("serve.e2e_ms", (done - t.submit_us) / 1e3)
+        obs.record_span("serve.batch", launched.start_us, launched.attrs)
+
+    def _fail(self, launched: _Launched, e: Exception) -> None:
+        obs.inc_counter("serve.batch_failed", type=type(e).__name__)
+        log.warning("batch of %d failed (%s: %s)", len(launched.batch),
+                    type(e).__name__, e)
+        for t in launched.batch:
+            t._resolve(exc=e)
+        obs.record_span("serve.batch", launched.start_us, launched.attrs)
 
     # ---------------------------------------------------------- tier upgrade
     def _upgrade_loop(self) -> None:

@@ -141,13 +141,13 @@ def test_queue_full_is_typed_and_never_deadlocks(cache):
                       queue_capacity=2)
     with ServeEngine(cfg, cache=cache) as eng:
         release = threading.Event()
-        real_run = eng._backend.run
+        real_launch = eng._backend.launch
 
-        def stalled_run(prepared, payloads):
+        def stalled_launch(prepared, payloads):
             assert release.wait(30.0), "test released too late"
-            return real_run(prepared, payloads)
+            return real_launch(prepared, payloads)
 
-        eng._backend.run = stalled_run
+        eng._backend.launch = stalled_launch
         tickets, rejected = [], 0
         for i in range(cfg.queue_capacity + cfg.max_batch + 4):
             try:
@@ -306,26 +306,159 @@ def test_serve_batch_span_carries_plan_attrs(cache):
 
 
 def test_served_batch_host_phases_nest_in_serve_batch(cache):
-    """One served batch emits each host phase once, in order, on the
-    worker's thread and inside that batch's ``serve.batch`` interval."""
-    cfg = ServeConfig(graph="tiny", max_batch=2, workers=1)
+    """Each served batch emits each host phase once, in order, on the
+    worker's thread and inside that batch's ``serve.batch`` interval; the
+    intervals of neighbouring batches may overlap (the pipeline)."""
+    cfg = ServeConfig(graph="tiny", max_batch=2, workers=1, queue_capacity=16)
     with ServeEngine(cfg, cache=cache) as eng:
         obs.reset()
         obs.enable()
-        eng.serve(_samples(eng, 1))
+        with _stalled_first_launch(eng) as (started, release):
+            tickets = [eng.submit(s) for s in _samples(eng, 1)]
+            assert started.wait(30.0)
+            tickets += [eng.submit(s) for s in _samples(eng, 6, seed=1)]
+            release.set()
+            for t in tickets:
+                t.result(timeout=30.0)
     spans = [e for e in obs.events() if e.get("ev") == "span"]
-    (batch,) = [e for e in spans if e["name"] == "serve.batch"]
+    batches = sorted((e for e in spans if e["name"] == "serve.batch"),
+                     key=lambda e: e["ts"])
+    assert len(batches) == 4
     phases = ("exec.assemble", "exec.network", "exec.split", "serve.wait",
               "serve.fetch")
-    got = [e for e in spans if e["name"] in phases]
-    assert sorted(e["name"] for e in got) == sorted(phases)
-    assert [e["name"] for e in sorted(got, key=lambda e: e["ts"])] == \
-        list(phases)
-    end = batch["ts"] + batch["dur"]
-    for e in got:
-        assert e["tid"] == batch["tid"]
-        assert e["depth"] > batch["depth"]
-        assert batch["ts"] <= e["ts"] and e["ts"] + e["dur"] <= end
+    by_phase = {p: sorted((e for e in spans if e["name"] == p),
+                          key=lambda e: e["ts"]) for p in phases}
+    for p in phases:
+        assert len(by_phase[p]) == len(batches), p
+    for i, batch in enumerate(batches):
+        got = [by_phase[p][i] for p in phases]
+        assert [e["ts"] for e in got] == sorted(e["ts"] for e in got)
+        end = batch["ts"] + batch["dur"]
+        for e in got:
+            assert e["tid"] == batch["tid"]
+            assert batch["ts"] <= e["ts"] and e["ts"] + e["dur"] <= end
+
+
+# ------------------------------------------------------------- pipeline
+class _stalled_first_launch:
+    """Hold the worker in its first launch until released, so what is
+    submitted meanwhile queues up as full batches."""
+
+    def __init__(self, eng):
+        self.eng = eng
+        self.started = threading.Event()
+        self.release = threading.Event()
+
+    def __enter__(self):
+        real = self.eng._backend.launch
+        calls = []
+
+        def launch(prepared, payloads):
+            calls.append(1)
+            if len(calls) == 1:
+                self.started.set()
+                assert self.release.wait(30.0), "test released too late"
+            return real(prepared, payloads)
+
+        self.eng._backend.launch = launch
+        return self.started, self.release
+
+    def __exit__(self, *exc):
+        self.release.set()
+        return False
+
+
+def test_queued_batches_overlap_and_match_serving_alone(cache):
+    cfg = ServeConfig(graph="tiny", max_batch=4, workers=1, queue_capacity=32)
+    with ServeEngine(cfg, cache=cache) as eng:
+        samples = _samples(eng, 17, seed=5)
+        with _stalled_first_launch(eng) as (started, release):
+            tickets = [eng.submit(samples[0])]
+            assert started.wait(30.0)
+            tickets += [eng.submit(s) for s in samples[1:]]   # 4 full batches
+            release.set()
+            got = [t.result(timeout=30.0) for t in tickets]
+        assert obs.counter_value("serve.batches_overlapped") >= 1
+        for i, s in enumerate(samples):
+            (alone,) = eng.serve([s])
+            assert np.array_equal(got[i], alone), i
+
+
+def test_lone_request_is_served_without_overlap(cache):
+    cfg = ServeConfig(graph="tiny", max_batch=4, workers=1)
+    with ServeEngine(cfg, cache=cache) as eng:
+        out = eng.submit(_samples(eng, 1)[0]).result(timeout=30.0)
+    assert out is not None and np.isfinite(out).all()
+    assert obs.counter_value("serve.batches") == 1
+    assert obs.counter_value("serve.batches_overlapped") == 0
+
+
+def test_failed_launch_fails_its_batch_alone(cache, monkeypatch):
+    from repro.plan.executor import PreparedNetwork
+
+    real = PreparedNetwork.execute_requests
+    started, release = threading.Event(), threading.Event()
+    calls = []
+
+    def flaky(self, samples, **kw):
+        calls.append(1)
+        if len(calls) == 1:
+            started.set()
+            assert release.wait(30.0), "test released too late"
+        if len(calls) == 2:
+            raise RuntimeError("planted launch fault")
+        return real(self, samples, **kw)
+
+    monkeypatch.setattr(PreparedNetwork, "execute_requests", flaky)
+    cfg = ServeConfig(graph="tiny", max_batch=4, workers=1, queue_capacity=16)
+    with ServeEngine(cfg, cache=cache) as eng:
+        samples = _samples(eng, 9, seed=7)
+        first = eng.submit(samples[0])
+        assert started.wait(30.0)
+        second = [eng.submit(s) for s in samples[1:5]]
+        third = [eng.submit(s) for s in samples[5:]]
+        release.set()
+        assert np.isfinite(first.result(timeout=30.0)).all()
+        for t in second:
+            with pytest.raises(RuntimeError, match="planted launch fault"):
+                t.result(timeout=30.0)
+        for t in third:
+            assert np.isfinite(t.result(timeout=30.0)).all()
+    assert obs.counter_value("serve.batch_failed", type="RuntimeError") == 1
+    assert obs.counter_value("serve.batches") == 2
+
+
+def test_stop_with_a_batch_in_flight_resolves_every_ticket(cache):
+    cfg = ServeConfig(graph="tiny", max_batch=2, workers=1, queue_capacity=16)
+    eng = ServeEngine(cfg, cache=cache).start()
+    real = eng._backend.launch
+    second, release = threading.Event(), threading.Event()
+    calls = []
+
+    def launch(prepared, payloads):
+        calls.append(1)
+        if len(calls) == 2:      # the first batch is launched, unfinished
+            second.set()
+            assert release.wait(30.0), "test released too late"
+        return real(prepared, payloads)
+
+    eng._backend.launch = launch
+    tickets = [eng.submit(s) for s in _samples(eng, 8, seed=9)]
+    assert second.wait(30.0)
+    stopper = threading.Thread(target=eng.stop)
+    stopper.start()
+    assert eng._stop.wait(30.0)
+    release.set()
+    stopper.join(timeout=60.0)
+    assert not stopper.is_alive()
+    for t in tickets:
+        assert t.done()
+    assert np.isfinite(tickets[0].result(timeout=0)).all()
+    for t in tickets[1:]:
+        try:
+            assert np.isfinite(t.result(timeout=0)).all()
+        except ServeError:
+            pass        # still queued at the stop
 
 
 # -------------------------------------------------------------- facade
